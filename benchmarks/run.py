@@ -77,6 +77,9 @@ def _take_flag(argv: list[str], flag: str, what: str) -> tuple[list[str], str | 
 
 
 def main() -> None:
+    from repro.caches import enable_compile_cache
+
+    enable_compile_cache()
     argv = sys.argv[1:]
     argv, emit_path = _take_flag(argv, "--emit", "an output path (e.g. --emit BENCH_kernels.json)")
     argv, trace_path = _take_flag(argv, "--trace", "a JSONL alive-mask trace path")

@@ -116,11 +116,11 @@ def scan_jaxpr_callbacks(jaxpr) -> list[str]:
 def _sub_jaxprs(param):
     """Yield any jaxprs nested inside an eqn param (ClosedJaxpr, Jaxpr, or
     (possibly nested) tuples/lists of them)."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
-    if isinstance(param, jax.core.ClosedJaxpr):
+    if isinstance(param, ClosedJaxpr):
         yield param.jaxpr
-    elif isinstance(param, jax.core.Jaxpr):
+    elif isinstance(param, Jaxpr):
         yield param
     elif isinstance(param, (tuple, list)):
         for p in param:

@@ -8,9 +8,11 @@ Lemma 3 states that for an assignment with Property 1 and recovery vector
                                                    the achieved a ≡ 1).
 
 :func:`resilient_sum` applies the combine host-side to stacked per-node
-statistics; :func:`resilient_psum` is the SPMD in-graph form (a weighted
-``psum`` over a mesh axis); :func:`mom_combine` is a byzantine-robust
-median-of-means alternative (paper §5 future-work direction).
+statistics; :func:`resilient_map_sum` evaluates and combines the nodes one
+at a time inside a compiled step; :func:`resilient_psum` is the SPMD
+in-graph form (a weighted ``psum`` over a mesh axis); :func:`mom_combine`
+is a byzantine-robust median-of-means alternative (paper §5 future-work
+direction).
 """
 
 from __future__ import annotations
@@ -21,7 +23,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["resilient_sum", "resilient_psum", "mom_combine", "weighted_union"]
+__all__ = [
+    "resilient_sum", "resilient_map_sum", "resilient_psum", "mom_combine", "weighted_union",
+]
 
 
 def resilient_sum(per_node_stats: Any, b_full: np.ndarray) -> Any:
@@ -38,6 +42,27 @@ def resilient_sum(per_node_stats: Any, b_full: np.ndarray) -> Any:
         return jnp.sum(w * leaf, axis=0)
 
     return jax.tree_util.tree_map(combine, per_node_stats)
+
+
+def resilient_map_sum(fn, b, node_args: Sequence[Any], bcast: Sequence[Any] = ()) -> Any:
+    """``Σ_i b_i · fn(*node_args[..][i], *bcast)``, accumulated one node at a
+    time (a ``lax.scan``) inside the caller's compiled step.
+
+    A ``vmap`` followed by :func:`resilient_sum` would hold every node's
+    output at once — for a train step one full gradient tree per group,
+    which does not fit a chip's memory at published model widths.  The
+    fused masked steps of both executors combine through this one helper.
+    """
+    one = jax.eval_shape(fn, *(a[0] for a in node_args), *bcast)
+    acc0 = jax.tree_util.tree_map(lambda o: jnp.zeros(o.shape, o.dtype), one)
+
+    def body(acc, xs):
+        w, sl = xs
+        out = fn(*sl, *bcast)
+        return jax.tree_util.tree_map(lambda a, o: a + w.astype(o.dtype) * o, acc, out), None
+
+    acc, _ = jax.lax.scan(body, acc0, (jnp.asarray(b), tuple(node_args)))
+    return acc
 
 
 def resilient_psum(x: Any, my_weight, axis_name: str) -> Any:

@@ -166,9 +166,8 @@ def cyclic_assignment(n: int, s: int, ell: int) -> Assignment:
     if not 1 <= ell <= s:
         raise ValueError(f"need 1 <= ell <= s, got ell={ell}, s={s}")
     mat = np.zeros((s, n), dtype=np.uint8)
-    for j in range(n):
-        for r in range(ell):
-            mat[(j + r) % s, j] = 1
+    j = np.arange(n)
+    mat[(j[None, :] + np.arange(ell)[:, None]) % s, j[None, :]] = 1
     return Assignment(matrix=mat, scheme="cyclic", params={"ell": ell})
 
 

@@ -7,8 +7,9 @@ the recovery weights ``b`` (Lemma 3).  The *algorithms* (kmedian, pca,
 coreset, kmeans) define the per-node function; the *executor* decides where
 it runs:
 
-* :class:`LocalExecutor` — single process, all nodes as one ``jax.vmap``
-  batch (the seed repo's behaviour; default).
+* :class:`LocalExecutor` — single process: ``map_nodes`` runs all nodes as
+  one ``jax.vmap`` batch; the fused masked combine evaluates them one at a
+  time (:func:`~repro.core.aggregation.resilient_map_sum`).  The default.
 * :class:`~repro.launch.distributed.MeshExecutor` — every node is placed on
   a device of a 1-D ``("nodes",)`` mesh and the same per-node function runs
   under ``shard_map``; the alive/recovery mask is a *runtime input* of the
@@ -16,7 +17,7 @@ it runs:
   Lemma-3 combine (``core.aggregation``) executes on device as a ``psum``.
 
 Both executors compile the *identical* inner function (the mesh path merely
-splits the vmap batch across devices), so their outputs agree to float32
+splits the node batch across devices), so their outputs agree to float32
 round-off — `tests/test_distributed_executor.py` pins cost parity at 1e-5.
 
 Per-node functions must be *stable objects* (module-level or
@@ -34,7 +35,7 @@ import jax.numpy as jnp
 
 from ..analysis import compiled_path
 from ..obs import trace_span
-from .aggregation import resilient_sum
+from .aggregation import resilient_map_sum, resilient_sum
 from .recovery import jax_recovery_masked
 
 __all__ = ["Executor", "LocalExecutor", "get_executor"]
@@ -145,7 +146,8 @@ class Executor:
 
 
 class LocalExecutor(Executor):
-    """All nodes simulated in one process as a single vmapped batch."""
+    """All nodes simulated in one process on one device: a single vmapped
+    batch for ``map_nodes``, a scan over nodes for the masked combine."""
 
     name = "local"
 
@@ -179,8 +181,6 @@ class LocalExecutor(Executor):
         separately from :meth:`_compiled_masked` so the Layer-2 jaxpr audit
         (:mod:`repro.analysis.jaxpr_audit`) can trace and instrument the raw
         python callable the hot path actually jits."""
-        in_axes = (0,) * n_node + (None,) * n_bcast
-        inner = jax.vmap(fn, in_axes=in_axes)
 
         def step(A, alive, use_override, b_override, *args):
             solved = jax_recovery_masked(A, alive, iters=iters)
@@ -188,8 +188,8 @@ class LocalExecutor(Executor):
             # fallbacks flow through THIS program with use_override=True
             # instead of compiling a second full program.
             b_full = jnp.where(use_override, b_override, solved)
-            per_node = inner(*args)
-            return resilient_sum(per_node, b_full), b_full
+            # One node at a time, like the mesh executor's per-device block.
+            return resilient_map_sum(fn, b_full, args[:n_node], args[n_node:]), b_full
 
         return step
 

@@ -136,7 +136,13 @@ def lp_recovery(assignment: Assignment, alive: np.ndarray) -> RecoveryResult:
     covered = np.flatnonzero(A_R.sum(axis=0) > 0)
     if covered.size == 0:
         return _result(A, alive_idx, np.zeros(r), "lp")
-    Ac = A_R[:, covered]  # (r, m)
+    # Shards with the same alive-replica set give the same two constraints;
+    # keep one of each (at a million points the dense constraint matrix
+    # would otherwise not fit, while a structured scheme has ≤ s patterns).
+    packed = np.packbits(A_R[:, covered] > 0, axis=0)  # one bit per alive node
+    rows = np.ascontiguousarray(packed.T).view(np.dtype((np.void, packed.shape[0])))
+    _, first = np.unique(rows.ravel(), return_index=True)
+    Ac = A_R[:, covered[first]]  # (r, m) distinct columns
     m = Ac.shape[1]
     # Variables x = [b (r), z (1)].
     c = np.zeros(r + 1)

@@ -12,29 +12,35 @@ import numpy as np
 __all__ = ["shard_batch", "markov_tokens", "make_markov_table"]
 
 
-def make_markov_table(vocab: int, *, seed: int = 0, concentration: float = 0.3):
-    """A sparse-ish Markov transition table — gives the LM something
-    learnable so loss curves in tests/examples actually descend."""
+def make_markov_table(vocab: int, *, seed: int = 0, fav_mass: float = 0.5):
+    """A sparse Markov chain — gives the LM something learnable so loss
+    curves in tests/examples actually descend.
+
+    Each token prefers 4 successors, which share ``fav_mass`` of its
+    transition probability; the rest is uniform over the vocabulary.  Kept
+    as the (V, 4) successor table and the cumulative split, so its size is
+    linear in V: a dense V×V table at a published vocabulary (151,936 for
+    qwen3) would not fit in host memory.
+    """
     rng = np.random.default_rng(seed)
-    logits = rng.gumbel(size=(vocab, vocab)) * concentration
-    # Each row strongly prefers a handful of successors.
     fav = rng.integers(0, vocab, size=(vocab, 4))
-    for v in range(vocab):
-        logits[v, fav[v]] += 4.0
-    p = np.exp(logits - logits.max(1, keepdims=True))
-    return p / p.sum(1, keepdims=True)
+    split = rng.dirichlet(np.ones(4), size=vocab)
+    return fav, np.cumsum(split, axis=1) * fav_mass
 
 
 def markov_tokens(table, n: int, T: int, *, seed: int) -> np.ndarray:
+    fav, cdf = table
     rng = np.random.default_rng(seed)
-    V = table.shape[0]
+    V = fav.shape[0]
     out = np.empty((n, T), dtype=np.int32)
     cur = rng.integers(0, V, size=n)
     out[:, 0] = cur
     for t in range(1, T):
         u = rng.random(n)
-        cdf = table[cur].cumsum(axis=1)
-        cur = (u[:, None] < cdf).argmax(axis=1)
+        c = cdf[cur]
+        j = (u[:, None] < c).argmax(axis=1)
+        uniform = rng.integers(0, V, size=n)
+        cur = np.where(u < c[:, -1], fav[cur, j], uniform)
         out[:, t] = cur
     return out
 

@@ -22,11 +22,11 @@ at bench shape was the motivating case).  This module flips selection to
   measurably faster than it — "no measured win" resolves to ref, never to
   a fashionable streaming rung.
 * **Versioned, self-healing persistence.**  Winners persist to one JSON
-  file per ``(backend, device kind)`` under ``~/.cache/repro``
-  (``REPRO_AUTOTUNE_CACHE`` overrides; ``0``/``off`` disables).  Writes are
-  atomic (tmp file + rename) and merge entries a concurrent process saved
-  between our load and our save; corrupt, stale-version, or foreign-device
-  files are ignored and overwritten by the next measurement.
+  file per ``(backend, device kind)`` in ``.autotune_cache/`` inside the
+  checkout (``REPRO_AUTOTUNE_CACHE`` overrides; ``0``/``off`` disables).
+  Writes are atomic (tmp file + rename) and merge entries a concurrent
+  process saved between our load and our save; corrupt, stale-version, or
+  foreign-device files are ignored and overwritten by the next measurement.
 * **Warm-start.**  :func:`warmup` runs a tier-declared plan of callables
   (pre-measuring buckets and pre-compiling programs) off the hot path —
   the serving frontend, streaming session, and trainer each declare their
@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import logging
 import os
 import re
 import tempfile
@@ -50,6 +51,8 @@ import time
 from typing import Any, Callable, Dict, Iterable, Optional, Sequence, Tuple
 
 import jax
+
+_log = logging.getLogger(__name__)
 
 __all__ = [
     "BlockConfig",
@@ -151,11 +154,8 @@ def device_kind() -> str:
     processes only within the same hardware generation, so the persistent
     cache is keyed on (backend, device kind).
     """
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # pragma: no cover - no devices initialized
-        kind = "unknown"
-    return re.sub(r"[^A-Za-z0-9_.-]+", "-", str(kind)).strip("-") or "unknown"
+    kind = jax.devices()[0].device_kind  # no device is an error, not a kind
+    return re.sub(r"[^A-Za-z0-9_.-]+", "-", str(kind)).strip("-")
 
 
 # ------------------------------------------------------------ shape buckets
@@ -190,7 +190,7 @@ class _RegistryStats:
     plain dict, but the numbers surface in obs-report too."""
 
     FIELDS = (
-        "hits", "misses", "measured", "errors",
+        "hits", "misses", "measured", "errors", "warmup_errors",
         "budget_stops", "deferred", "disk_loaded", "disk_errors",
     )
 
@@ -261,14 +261,18 @@ def autotune_cache_dir() -> Optional[str]:
     """Directory for persisted winners; None disables persistence.
 
     ``REPRO_AUTOTUNE_CACHE`` overrides (``0``/``off``/``none`` to disable);
-    default is ``~/.cache/repro``.
+    the default is a fixed directory inside the checkout
+    (:data:`repro.caches.AUTOTUNE_CACHE_DIR`), so two checkouts measured on
+    one machine never share winners.
     """
     v = os.environ.get(AUTOTUNE_CACHE_ENV)
     if v is not None:
         if v.strip().lower() in ("", "0", "off", "none", "false"):
             return None
         return os.path.expanduser(v)
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro")
+    from ..caches import AUTOTUNE_CACHE_DIR
+
+    return str(AUTOTUNE_CACHE_DIR)
 
 
 def autotune_cache_file() -> Optional[str]:
@@ -415,11 +419,18 @@ def _time_once(item, *, reps: Optional[int] = None) -> float:
     return times[len(times) // 2]
 
 
-def _trace_clean() -> bool:
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - very old/new jax
+def _deferred(inputs: Sequence[Any]) -> bool:
+    """Measurement is eager-only: when the op's own ``inputs`` are tracers
+    (it is being staged into an outer ``jit``/``vmap``) the bench inputs
+    would be staged too and nothing could execute.  The public ops resolve
+    eagerly and the warm-up plans run eagerly, which is where buckets get
+    measured; traced code then reads the caches (or gets the analytic
+    default, uncached, so a later eager call still measures)."""
+    leaves = jax.tree_util.tree_leaves(inputs)
+    if any(isinstance(leaf, jax.core.Tracer) for leaf in leaves):
+        _AUTOTUNE_STATS["deferred"] += 1
         return True
+    return False
 
 
 def _measure_pass(ordered: Sequence, bench: Callable) -> Dict:
@@ -428,17 +439,8 @@ def _measure_pass(ordered: Sequence, bench: Callable) -> Dict:
     The caller puts the analytic default FIRST: if the budget truncates the
     pass, the prior has been measured and later candidates simply never get
     the chance to displace it.  Candidates that fail to compile never win.
-
-    Returns ``{}`` ("measurement deferred") when a jax trace is active:
-    inside a trace the bench inputs would be staged as tracers and nothing
-    can execute, so measurement only runs from eager context — the public
-    ops resolve eagerly and the warm-up plans run eagerly, which is where
-    buckets get measured; traced code then reads the caches.
     """
     times: Dict = {}
-    if not _trace_clean():
-        _AUTOTUNE_STATS["deferred"] += 1
-        return times
     from ..obs import trace_span
 
     budget = measure_budget_s()
@@ -451,6 +453,7 @@ def _measure_pass(ordered: Sequence, bench: Callable) -> Dict:
             try:
                 t = _time_once(bench(cand))
             except Exception:
+                _log.warning("autotune candidate %r failed", cand, exc_info=True)
                 _AUTOTUNE_STATS["errors"] += 1
                 continue
             _AUTOTUNE_STATS["measured"] += 1
@@ -494,6 +497,7 @@ def tuned_block_config(
     default: BlockConfig,
     candidates: Sequence[BlockConfig] = (),
     bench: Optional[Callable[[BlockConfig], Callable[[], Any]]] = None,
+    inputs: Sequence[Any] = (),
 ) -> BlockConfig:
     """Block config for ``op`` at the given shape bucket.
 
@@ -508,7 +512,9 @@ def tuned_block_config(
 
     ``bench(cfg)`` must return ``(fn, args)`` — ``fn`` jitted and timed on
     the synthetic ``args`` — or a legacy zero-arg callable (which risks
-    constant folding; see :func:`_time_once`).
+    constant folding; see :func:`_time_once`).  ``inputs`` are the op's own
+    arguments: when they are tracers the measurement defers (see
+    :func:`_deferred`).
     """
     if autotune_enabled():
         # Hydrate measured winners from previous processes on this hardware
@@ -527,11 +533,12 @@ def tuned_block_config(
         return default
     _AUTOTUNE_STATS["misses"] += 1
     ordered = [default] + [c for c in candidates if c != default]
+    if _deferred(inputs):
+        return default
     times = _measure_pass(ordered, bench)
     if not times:
-        # Measurement deferred (active trace) or every candidate errored —
-        # stay on the analytic default WITHOUT caching it, so a later eager
-        # call still gets its chance to measure this bucket.
+        # Every candidate errored — stay on the analytic default WITHOUT
+        # caching it, so a later call still gets its chance to measure.
         return default
     best = _pick(times, default)
     _AUTOTUNE_CACHE[key] = best
@@ -548,6 +555,7 @@ def tuned_strategy(
     candidates: Sequence[str] = (),
     bench: Optional[Callable[[str], Callable[[], Any]]] = None,
     baseline: Optional[str] = None,
+    inputs: Sequence[Any] = (),
 ) -> str:
     """Strategy (ladder-rung) choice for ``op`` at the given shape bucket.
 
@@ -572,9 +580,11 @@ def tuned_strategy(
         return default
     _AUTOTUNE_STATS["misses"] += 1
     ordered = [default] + [c for c in candidates if c != default]
+    if _deferred(inputs):
+        return default
     times = _measure_pass(ordered, bench)
     if not times:
-        return default  # deferred or all-errored: uncached, retry eagerly later
+        return default  # all-errored: uncached, retry eagerly later
     best = _pick(times, default, baseline=baseline)
     _STRATEGY_CACHE[key] = best
     _persist_save()
@@ -633,6 +643,8 @@ def warmup(plan: Iterable) -> WarmupReport:
                 report.warmed += 1
                 labels.append(str(label))
             except Exception:
+                _log.warning("warm-up entry %r failed", label, exc_info=True)
+                _AUTOTUNE_STATS["warmup_errors"] += 1
                 report.errors += 1
         sp.set_attr(warmed=report.warmed, errors=report.errors)
     report.seconds = time.perf_counter() - t0

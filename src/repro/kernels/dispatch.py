@@ -21,7 +21,8 @@ The module also owns the *analytic* cross-op sizing policies:
 
 * :func:`pick_blocks` — one VMEM-aware block-size model: choose ``(bn, bk)``
   so the f32 working set ``(bn·d + bk·d + bn·bk)·itemsize`` fits a VMEM
-  budget, preferring MXU-aligned powers of two.
+  budget, in multiples of the TPU lane width (128) that the chip's compiler
+  accepts.
 * :func:`should_stream` — whether an op should take a chunked/streaming path
   instead of materializing an ``(n, k)`` intermediate.
 * :func:`ladder_strategy` — the ref/broadcast/chunked assignment ladder.
@@ -79,6 +80,7 @@ __all__ = [
     "autotune_cache_info",
     "autotune_enabled",
     "backend",
+    "block_footprint",
     "clear_autotune_cache",
     "device_kind",
     "dispatch",
@@ -106,14 +108,27 @@ __all__ = [
 INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
 
 # Default budgets of the shared sizing model.  VMEM_BUDGET bounds the per-tile
-# working set of the Pallas kernels (a conservative quarter of a TPU core's
-# ~16 MB VMEM); MATERIALIZE_BUDGET bounds how large an (n, k) intermediate an
-# op may materialize before auto-dispatch switches to a streaming path.
-VMEM_BUDGET = 4 * 1024 * 1024
+# working set of the Pallas kernels, as block_footprint counts it, at three
+# quarters of the 16 MiB that Mosaic lets one kernel use by default on a v5e
+# (the rest is the compiler's own scratch); MATERIALIZE_BUDGET bounds how
+# large an (n, k) intermediate an op may materialize before auto-dispatch
+# switches to a streaming path.
+VMEM_BUDGET = 12 * 1024 * 1024
 MATERIALIZE_BUDGET = 32 * 1024 * 1024
 
-_MXU_LANE = 128
-_SUBLANE = 8
+# TPU vector lane width.  Every block size pick_blocks returns is a multiple
+# of it: bn is the lane axis of the kernels' lane-dense (1, bn) per-point
+# rows, bk the lane axis of pairwise_sqdist's (bn, bk) output tile, and both
+# are MXU-aligned.  A multiple of 128 is also a multiple of the sublane
+# count (8), so every block meets the chip's (8, 128) tiling rule.
+LANE = 128
+
+# Precision of every matmul in the clustering kernels and their XLA paths:
+# full f32.  At the default precision a TPU rounds f32 operands to bf16,
+# which moves a squared distance ‖x‖² + ‖c‖² − 2·x·c by about 2⁻⁸·‖x‖² —
+# more than the distance from a point to its own center in typical data —
+# so the nearest center would be picked from rounding noise.
+MATMUL_PRECISION = "highest"
 
 
 def interpret_enabled() -> bool:
@@ -224,6 +239,19 @@ def dispatch(op: str, impl: str, *args: Any, **kwargs: Any) -> Any:
 # ------------------------------------------------------- block-size model
 
 
+def block_footprint(bn: int, bk: int, d: int, *, itemsize: int = 4) -> int:
+    """VMEM bytes of one grid step as the chip holds it.
+
+    The x-tile (bn, d) and the c-tile (bk, d) are double-buffered by the
+    pipeline and split into three bf16 terms for the f32 contraction
+    (``2·itemsize + 6`` bytes an element); the (bn, bk) product tile is held
+    with one masked copy.  For weighted_segsum bk is the whole k: the (k, d)
+    accumulator and the (k, bn) one-hot.  Calibrated against compiles for a
+    described v5e: at d=2048 a (512, 256) assign_min tile needs 16.4 MiB.
+    """
+    return (bn + bk) * d * (2 * itemsize + 6) + 2 * bn * bk * itemsize
+
+
 def pick_blocks(
     n: int,
     k: int,
@@ -231,24 +259,23 @@ def pick_blocks(
     *,
     itemsize: int = 4,
     vmem_budget: int = VMEM_BUDGET,
-    bn_cap: int = 256,
-    bk_cap: int = _MXU_LANE,
+    bn_cap: int = 512,
+    bk_cap: int = 2 * LANE,
 ) -> BlockConfig:
     """The one VMEM-aware tile model shared by every blocked op.
 
-    Working set per grid step is the x-tile (bn, d), the c-tile (bk, d) and
-    the (bn, bk) product tile; all f32 in VMEM.  Start from MXU-aligned caps
-    and halve (bn first — it has the bigger footprint) until the set fits.
+    Start from the caps (clamped to the padded problem size, never below one
+    lane) and halve — bn first, it has the bigger footprint — until
+    :func:`block_footprint` fits the budget or both sizes reach 128.  Both
+    sizes stay multiples of :data:`LANE`, the only block shapes the TPU
+    compiler accepts for these kernels, plain and under ``jax.vmap``.
+    Callers pad their operands up to the returned multiples.
     """
-    bn = max(_SUBLANE, min(bn_cap, _pow2_ceil(n)))
-    bk = max(_SUBLANE, min(bk_cap, _pow2_ceil(k)))
-
-    def footprint(bn_: int, bk_: int) -> int:
-        return (bn_ * d + bk_ * d + bn_ * bk_) * itemsize
-
-    while bn > _SUBLANE and footprint(bn, bk) > vmem_budget:
+    bn = max(LANE, min(bn_cap, _pow2_ceil(n)))
+    bk = max(LANE, min(bk_cap, _pow2_ceil(k)))
+    while bn > LANE and block_footprint(bn, bk, d, itemsize=itemsize) > vmem_budget:
         bn //= 2
-    while bk > _SUBLANE and footprint(bn, bk) > vmem_budget:
+    while bk > LANE and block_footprint(bn, bk, d, itemsize=itemsize) > vmem_budget:
         bk //= 2
     return BlockConfig(bn=bn, bk=bk)
 

@@ -83,7 +83,7 @@ def _flash_kernel(
 
 def flash_attention_kernel_call(
     q, k, v, *, group: int, causal: bool, scale: float,
-    bq: int = 512, bk: int = 512, interpret: bool = True,
+    bq: int = 512, bk: int = 512, interpret: bool,
 ):
     """q: (BH, T, dh); k, v: (BKV, S, dh) with BH = BKV · group.
 

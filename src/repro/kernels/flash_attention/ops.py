@@ -100,6 +100,15 @@ def _pallas_attention(q, k, v, *, causal, window, scale, interpret):
         # Windowed attention falls through to chunked (structural skipping
         # already yields the T·W cost there).
         return chunked_attention(q, k, v, causal=causal, window=window, scale=scale)
+    return _pallas_forward(q, k, v, causal, scale, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _pallas_forward(q, k, v, causal, scale, interpret):
+    """The kernel's forward pass.  The kernel has no backward of its own
+    (pallas_call cannot differentiate a kernel with scratch state), so the
+    gradient comes from :func:`chunked_attention`'s — the same function,
+    recomputed in XLA — which is what lets a train step use the kernel."""
     B, T, H, dh = q.shape
     S, KV = k.shape[1], k.shape[2]
     g = H // KV
@@ -119,6 +128,20 @@ def _pallas_attention(q, k, v, *, causal, window, scale, interpret):
         bq=bq, bk=bk, interpret=interpret,
     )
     return out.reshape(B, H, T, dh).transpose(0, 2, 1, 3)
+
+
+def _pallas_forward_fwd(q, k, v, causal, scale, interpret):
+    return _pallas_forward(q, k, v, causal, scale, interpret), (q, k, v)
+
+
+def _pallas_forward_bwd(causal, scale, interpret, res, g):
+    _, vjp = jax.vjp(
+        lambda q, k, v: chunked_attention(q, k, v, causal=causal, scale=scale), *res
+    )
+    return vjp(g)
+
+
+_pallas_forward.defvjp(_pallas_forward_fwd, _pallas_forward_bwd)
 
 
 def _ref_attention(q, k, v, *, causal, window, scale):
@@ -182,7 +205,7 @@ def _select_attention(b, q, k, v, causal, window, scale):
     return dispatch.tuned_strategy(
         "flash_attention_strategy", (B, T, H, S, KV, dh), q.dtype,
         default=prior, candidates=("xla_ref", "xla_chunked"), bench=bench,
-        baseline="xla_ref",
+        baseline="xla_ref", inputs=(q, k, v),
     )
 
 

@@ -43,17 +43,20 @@ def _pad_to(x, m, axis, value=0.0):
 # ------------------------------------------------------------ pallas paths
 
 
-def _tuned_cfg(op, n, k, d, dtype, interpret, run_with_cfg):
+def _tuned_cfg(op, x, c, interpret, run_with_cfg):
     """Shared-model block config, refined by the measured-autotune cache."""
+    n, d = x.shape
+    k = c.shape[0]
+    dtype = x.dtype
     default = dispatch.pick_blocks(n, k, d)
     if interpret:  # debug path — measuring the interpreter is meaningless
         return default
     if not dispatch.worth_measuring(n * k * 4):
         return default  # below the floor the model is within noise of optimal
     cands = {default}
-    if default.bn > 8:
+    if default.bn > dispatch.LANE:
         cands.add(dispatch.BlockConfig(default.bn // 2, default.bk))
-    if default.bk > 8:
+    if default.bk > dispatch.LANE:
         cands.add(dispatch.BlockConfig(default.bn, default.bk // 2))
 
     def bench(cfg):
@@ -66,7 +69,7 @@ def _tuned_cfg(op, n, k, d, dtype, interpret, run_with_cfg):
     return dispatch.tuned_block_config(
         op, (n, k, d), dtype, default=default, candidates=sorted(
             cands, key=lambda c: (c.bn, c.bk)
-        ), bench=bench,
+        ), bench=bench, inputs=(x, c),
     )
 
 
@@ -81,10 +84,8 @@ def _sqdist_pallas_cfg(x, c, cfg, interpret):
 
 
 def _sqdist_pallas(x, c, *, interpret: bool):
-    n, d = x.shape
-    k = c.shape[0]
     cfg = _tuned_cfg(
-        "pairwise_sqdist", n, k, d, x.dtype, interpret,
+        "pairwise_sqdist", x, c, interpret,
         lambda xs, cs, cf: _sqdist_pallas_cfg(xs, cs, cf, False),
     )
     return _sqdist_pallas_cfg(x, c, cfg, interpret)
@@ -103,10 +104,8 @@ def _assign_pallas_cfg(x, c, cfg, interpret):
 
 
 def _assign_pallas(x, c, *, interpret: bool):
-    n, d = x.shape
-    k = c.shape[0]
     cfg = _tuned_cfg(
-        "assign_min", n, k, d, x.dtype, interpret,
+        "assign_min", x, c, interpret,
         lambda xs, cs, cf: _assign_pallas_cfg(xs, cs, cf, False),
     )
     return _assign_pallas_cfg(x, c, cfg, interpret)
@@ -144,7 +143,8 @@ def _assign_min_chunked_bk(x, c, bk: int):
         best_d, best_i = carry
         cb = jax.lax.dynamic_slice_in_dim(cp, j * bk, bk, axis=0)  # (bk, d)
         c2 = jnp.sum(cb * cb, axis=1)
-        d2 = jnp.maximum(x2[:, None] + c2[None, :] - 2.0 * (xf @ cb.T), 0.0)
+        xc = jnp.matmul(xf, cb.T, precision=dispatch.MATMUL_PRECISION)
+        d2 = jnp.maximum(x2[:, None] + c2[None, :] - 2.0 * xc, 0.0)
         col = j * bk + jnp.arange(bk)
         d2 = jnp.where(col[None, :] < k, d2, _PAD_DIST)
         loc_i = jnp.argmin(d2, axis=1).astype(jnp.int32)
@@ -201,7 +201,8 @@ def _assign_min_broadcast_cfg(x, c, cfg):
     )
 
     def body(carry, xb):
-        s = (c2[None, :] - 2.0 * (xb @ cp.T)).reshape(bn, kp // kb, kb)
+        xc = jnp.matmul(xb, cp.T, precision=dispatch.MATMUL_PRECISION)
+        s = (c2[None, :] - 2.0 * xc).reshape(bn, kp // kb, kb)
         bm = jnp.min(s, axis=2)                                   # (bn, kp/kb)
         wb = jnp.argmin(bm, axis=1).astype(jnp.int32)             # winning block
         win = jnp.take_along_axis(s, wb[:, None, None], axis=1)[:, 0, :]
@@ -239,6 +240,7 @@ def _assign_min_broadcast(x, c):
     cfg = dispatch.tuned_block_config(
         "assign_min_broadcast", (n, k, d), x.dtype, default=default,
         candidates=sorted(cands, key=lambda c_: (c_.bn, c_.bk)), bench=bench,
+        inputs=(x, c),
     )
     return _assign_min_broadcast_cfg(x, c, cfg)
 
@@ -265,7 +267,7 @@ def _assign_min_chunked(x, c):
         "assign_min_chunked", (n, k, d), x.dtype,
         default=dispatch.BlockConfig(0, default_bk),
         candidates=[dispatch.BlockConfig(0, b) for b in cands],
-        bench=bench,
+        bench=bench, inputs=(x, c),
     )
     return _assign_min_chunked_bk(x, c, cfg.bk)
 
@@ -362,7 +364,7 @@ def _select_assign(b, x, c):
     return dispatch.tuned_strategy(
         "assign_min_strategy", (n, k, d), x.dtype, default=impl,
         candidates=tuple(cands), bench=bench,
-        baseline="xla_ref" if ref_feasible else None,
+        baseline="xla_ref" if ref_feasible else None, inputs=(x, c),
     )
 
 

@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ..dispatch import MATMUL_PRECISION
+
 __all__ = ["pairwise_sqdist_ref", "assign_min_ref"]
 
 
@@ -17,7 +19,7 @@ def pairwise_sqdist_ref(x, c):
     c = c.astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=1, keepdims=True)  # (n, 1)
     c2 = jnp.sum(c * c, axis=1)[None, :]  # (1, k)
-    d2 = x2 + c2 - 2.0 * (x @ c.T)
+    d2 = x2 + c2 - 2.0 * jnp.matmul(x, c.T, precision=MATMUL_PRECISION)
     return jnp.maximum(d2, 0.0)
 
 

@@ -29,9 +29,9 @@ __all__ = ["weighted_segsum"]
 def _segsum_pallas(x, w, idx, k: int, *, interpret: bool):
     n, d = x.shape
     # Same VMEM model as the pairwise kernels: working set is the (bn, d)
-    # x-tile, the (bn, k) one-hot and the (k, d) accumulator — exactly
-    # pick_blocks' footprint with bk pinned to (padded) k.
-    bn = dispatch.pick_blocks(n, k, d, bn_cap=512, bk_cap=max(8, k)).bn
+    # x-tile, the (k, bn) one-hot and the (k, d) accumulator — exactly
+    # pick_blocks' footprint with bk pinned to the whole k.
+    bn = dispatch.pick_blocks(n, k, d, bk_cap=dispatch.shape_bucket(k)).bn
     rem = (-n) % bn
     if rem:
         x = jnp.pad(x, ((0, rem), (0, 0)))
@@ -73,7 +73,14 @@ _ONEHOT_BUDGET = 1 << 20
 
 def _select_segsum(b, x, w, idx, k):
     if b == "tpu":
-        return "pallas_tpu"
+        # The kernel keeps the whole (k, d) accumulator and a (k, bn) one-hot
+        # in VMEM; past the budget even at the smallest row block, the
+        # scatter-add streams instead.
+        d = x.shape[1]
+        fits = dispatch.block_footprint(
+            dispatch.LANE, dispatch.shape_bucket(k), d
+        ) <= dispatch.VMEM_BUDGET
+        return "pallas_tpu" if fits else "xla_segment"
     return (
         "xla_segment"
         if dispatch.should_stream(x.shape[0], k, budget=_ONEHOT_BUDGET)

@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ..dispatch import MATMUL_PRECISION
+
 __all__ = ["weighted_segsum_ref"]
 
 
@@ -19,4 +21,4 @@ def weighted_segsum_ref(x, w, idx, k: int):
     w = w.astype(jnp.float32)
     oh = (idx[:, None] == jnp.arange(k)[None, :]).astype(jnp.float32)  # (n, k)
     oh = oh * w[:, None]
-    return oh.T @ x, jnp.sum(oh, axis=0)
+    return jnp.matmul(oh.T, x, precision=MATMUL_PRECISION), jnp.sum(oh, axis=0)

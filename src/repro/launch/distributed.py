@@ -11,8 +11,7 @@ actual distributed program:
   of nodes per device.
 * **Local solve** — the algorithm's per-node function (local k-median Lloyd,
   coreset sampling, PCA sketch, cost evaluation …) runs node-parallel under
-  ``shard_map`` (via the version-compat shims in :mod:`repro.launch.compat`),
-  vmapped over the node block a device owns.
+  ``jax.shard_map``, vmapped over the node block a device owns.
 * **Straggler mask** — the recovery weights ``b_full`` (zero at stragglers,
   from :mod:`repro.core.recovery` over an alive mask from
   :mod:`repro.core.stragglers`) enter the compiled step as a *runtime array
@@ -40,14 +39,13 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from ..analysis import compiled_path
-from ..core.aggregation import resilient_psum, resilient_sum
+from ..core.aggregation import resilient_map_sum, resilient_psum, resilient_sum
 from ..core.executor import Executor
 from ..core.recovery import jax_recovery_masked
 from ..obs import trace_span
-from .compat import make_auto_mesh, shard_map
 
 __all__ = ["MeshExecutor", "node_mesh"]
 
@@ -57,7 +55,10 @@ NODE_AXIS = "nodes"
 def node_mesh(devices: Optional[Sequence[jax.Device]] = None):
     """1-D mesh over ``devices`` (default: all visible) with axis "nodes"."""
     devices = tuple(devices) if devices is not None else tuple(jax.devices())
-    return make_auto_mesh((len(devices),), (NODE_AXIS,), devices=np.array(devices))
+    return jax.make_mesh(
+        (len(devices),), (NODE_AXIS,), axis_types=(AxisType.Auto,),
+        devices=np.array(devices),
+    )
 
 
 class MeshExecutor(Executor):
@@ -132,7 +133,7 @@ class MeshExecutor(Executor):
             in_specs = (P(NODE_AXIS),) * n_node + (P(),) * n_bcast
             out_specs = P(NODE_AXIS)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             step, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
@@ -166,20 +167,19 @@ class MeshExecutor(Executor):
     def _masked_step_raw(self, fn: Callable, n_node: int, n_bcast: int, iters: int):
         """The UNCOMPILED fused per-device step (must run under shard_map) —
         exposed for the Layer-2 jaxpr audit, same contract as
-        :meth:`repro.core.executor.LocalExecutor._masked_step_raw`."""
-        in_axes = (0,) * n_node + (None,) * n_bcast
-        inner = jax.vmap(fn, in_axes=in_axes)
+        :meth:`repro.core.executor.LocalExecutor._masked_step_raw`: each
+        device accumulates its node block one node at a time
+        (:func:`repro.core.aggregation.resilient_map_sum`), then psums."""
 
         def step(A, alive, use_override, b_override, *args):
             solved = jax_recovery_masked(A, alive, iters=iters)
             # Runtime select, not a Python branch: the fallback path shares
             # this one compiled program (see Executor.resilient_reduce_masked).
             b_full = jnp.where(use_override, b_override, solved)
-            per_node = inner(*args)
             blk = args[0].shape[0]  # this device's node-block size (static)
             i = jax.lax.axis_index(NODE_AXIS)
             b_blk = jax.lax.dynamic_slice(b_full, (i * blk,), (blk,))
-            local = resilient_sum(per_node, b_blk)
+            local = resilient_map_sum(fn, b_blk, args[:n_node], args[n_node:])
             return resilient_psum(local, jnp.float32(1.0), NODE_AXIS), b_full
 
         return step
@@ -198,7 +198,7 @@ class MeshExecutor(Executor):
         step = self._masked_step_raw(fn, n_node, n_bcast, iters)
         in_specs = (P(), P(), P(), P()) + (P(NODE_AXIS),) * n_node + (P(),) * n_bcast
         out_specs = (P(), P())
-        sharded = shard_map(
+        sharded = jax.shard_map(
             step, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
@@ -255,7 +255,7 @@ class MeshExecutor(Executor):
                 return fn(*a)
 
             n = len(args)
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 step, mesh=self.mesh, in_specs=(P(),) * n, out_specs=P(),
                 check_vma=False,
             )

@@ -8,8 +8,7 @@ XLA_FLAGS before the first jax call, and smoke tests must see 1 device.
 from __future__ import annotations
 
 import jax
-
-from .compat import make_auto_mesh
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_test_mesh", "axis_sizes"]
 
@@ -30,12 +29,12 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None):
     else:
         shape = tuple(shape)
         axes = ("pod", "data", "model") if len(shape) == 3 else ("data", "model")
-    return make_auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU integration tests (requires forced host devices)."""
-    return make_auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def axis_sizes(mesh) -> dict:

@@ -20,6 +20,9 @@ from .train import _SCALES
 
 
 def main() -> None:
+    from ..caches import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
     ap.add_argument("--scale", default="smoke", choices=list(_SCALES))

@@ -28,7 +28,6 @@ from jax.sharding import PartitionSpec as P
 
 from . import layers as L
 from .registry import ModelConfig, MoEConfig
-from ..launch.compat import shard_map
 
 __all__ = ["moe_init", "moe_apply"]
 
@@ -239,7 +238,7 @@ def moe_apply(
             compute_dtype=compute_dtype, model_axis=model_axis, fsdp_axis=fsdp,
             act=cfg.mlp_act, batch_axes=tuple(batch_axes),
         )
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(batch_spec, None), P(None, None)) + weight_specs + (shared_specs,),
@@ -253,7 +252,7 @@ def moe_apply(
         _moe_inner, mcfg=m, capacity=capacity, compute_dtype=compute_dtype,
         model_axis=model_axis, fsdp_axis=fsdp, act=cfg.mlp_act,
     )
-    out = shard_map(
+    out = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(batch_spec, None), P(batch_spec, None)) + weight_specs + (shared_specs,),

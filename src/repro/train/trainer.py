@@ -68,8 +68,12 @@ def _jitted_train_step(cfg, ctx, opt_cfg, compression):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_apply_fn(opt_cfg, num_shards, compression):
+    # The state is donated: the update is written in place, so a step never
+    # holds two copies of the parameters and optimizer moments (at published
+    # model widths a second copy does not fit a chip).
     return jax.jit(
-        make_recovered_apply_fn(opt_cfg, num_shards, compression=compression)
+        make_recovered_apply_fn(opt_cfg, num_shards, compression=compression),
+        donate_argnums=0,
     )
 
 
@@ -243,12 +247,10 @@ class Trainer:
 
     # -------------------------------------------------- mesh-native step
 
-    @compiled_path("trainer.device_recovery_step", kind="host")
-    def _device_recovery_step(
-        self, state: TrainState, step: int, alive_t: np.ndarray
-    ) -> tuple[TrainState, Optional[dict]]:
-        """One step of the fused path.  Returns (state, record) — record is
-        ``None`` when every group straggled (step skipped)."""
+    def _recovered_stats(self, state: TrainState, step: int, alive_t: np.ndarray):
+        """The fused path's gradient program: per-group statistics combined
+        with the recovery weights solved on device.  Returns ``(stats, b,
+        covered)``, or ``None`` when every group straggled."""
         sess = self.plan.session
         ex = sess.executor
         A = sess.assignment.matrix.astype(np.float32)
@@ -266,7 +268,7 @@ class Trainer:
             # gradient program.
             w = self.plan.step_weights(alive_t)
             if not w.any():
-                return state, None  # every group straggled: skip the step
+                return None  # every group straggled
             b_override = w
         stats, b_dev = ex.resilient_reduce_masked(
             self._group_fn, node_args, bcast, A, alive_t,
@@ -274,6 +276,20 @@ class Trainer:
         )
         if covered:
             sess.stats.device_solves += 1
+        return stats, b_dev, covered
+
+    @compiled_path("trainer.device_recovery_step", kind="host")
+    def _device_recovery_step(
+        self, state: TrainState, step: int, alive_t: np.ndarray
+    ) -> tuple[TrainState, Optional[dict]]:
+        """One step of the fused path.  Returns (state, record) — record is
+        ``None`` when every group straggled (step skipped).  The input
+        ``state`` is donated to the update."""
+        out = self._recovered_stats(state, step, alive_t)
+        if out is None:
+            return state, None  # every group straggled: skip the step
+        stats, b_dev, covered = out
+        sess = self.plan.session
         state, metrics = self._apply_fn(state, stats)
         # ONE blocking device→host transfer per step: every per-step scalar
         # is fetched in a single device_get instead of a float() per metric.
@@ -303,12 +319,15 @@ class Trainer:
 
     def warmup(self, state: Optional[TrainState] = None) -> "autotune.WarmupReport":
         """Pre-compile the train step before the loop: ONE throwaway
-        all-alive step whose result state is discarded.
+        all-alive step whose result state is discarded (on the fused path
+        the gradient program runs and the state update, which donates its
+        input, is compiled without running, so ``state`` survives).
 
-        Executing (not just lowering) the step both compiles the program the
-        loop will reuse and triggers any pending autotune measurement for
-        its kernels, and on the mesh-native path it also seeds the pattern
-        cache with the all-alive pattern.  Session counters are snapshotted
+        Running the gradient program (or, off the fused path, the whole
+        step) compiles what the loop will reuse and triggers any pending
+        autotune measurement for its kernels, and on the mesh-native path it
+        also seeds the pattern cache with the all-alive pattern.  Session
+        counters are snapshotted
         and restored so the extra step is invisible to every stat the tests
         and benches assert on — only wall clock (reported) is spent.
         """
@@ -322,8 +341,11 @@ class Trainer:
 
         def one_step():
             if self.tcfg.device_recovery:
-                warm_state, _ = self._device_recovery_step(state, 0, alive)
-                return warm_state.params
+                # The update donates its state, so it is compiled, not run:
+                # the caller's state must survive the warm-up.
+                stats, _, _ = self._recovered_stats(state, 0, alive)
+                self._apply_fn.lower(state, stats).compile()
+                return stats
             batch = {
                 "tokens": jnp.asarray(self.pipeline.batch(0)),
                 # All-alive weights: compilation only depends on shape/dtype,
@@ -351,6 +373,14 @@ class Trainer:
         start_step: Optional[int] = None,
         on_step: Optional[Callable[[int, dict], None]] = None,
     ) -> TrainState:
+        """Train from ``state`` (default: :meth:`init_state`) to
+        ``tcfg.steps`` and return the final state.
+
+        On the fused path (``device_recovery=True``) each update donates its
+        input state, so a ``state`` passed in is consumed: its buffers are
+        deleted by the first step.  Keep the returned state, not the one
+        passed in (``state = trainer.run(state)``).
+        """
         if state is None:
             state, resumed = self.init_state()
             start_step = resumed if start_step is None else start_step

@@ -4,10 +4,11 @@
 these are the tier-1 regression pins.)
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.aggregation import mom_combine, resilient_sum
+from repro.core.aggregation import mom_combine, resilient_map_sum, resilient_sum
 
 
 def test_mom_combine_remainder_rows_not_dropped():
@@ -65,3 +66,21 @@ def test_resilient_sum_straggler_weights_zero_out_garbage():
         stats = jnp.asarray([[1.0, 1.0], [2.0, 2.0], [123.0, 456.0]], jnp.float32)
         out = np.asarray(resilient_sum(stats, b))
     np.testing.assert_allclose(out, [5.0, 5.0])
+
+
+def test_resilient_map_sum_matches_vmapped_combine():
+    """The executors' scan combine equals vmap + resilient_sum on a pytree
+    output, with one broadcast argument and zero weight at a straggler."""
+    rng = np.random.default_rng(0)
+    xs = jnp.asarray(rng.normal(size=(5, 6, 3)), jnp.float32)
+    ys = jnp.asarray(rng.normal(size=(5, 3)), jnp.float32)
+    c = jnp.asarray(rng.normal(size=(3,)), jnp.float32)
+    b = jnp.asarray([0.5, 1.0, 0.0, 2.0, 1.5], jnp.float32)
+
+    def fn(x, y, c):
+        return {"sum": jnp.sum(x * c, axis=0) + y, "sq": jnp.sum(x * x)}
+
+    want = resilient_sum(jax.vmap(fn, in_axes=(0, 0, None))(xs, ys, c), b)
+    got = jax.jit(lambda b, xs, ys, c: resilient_map_sum(fn, b, (xs, ys), (c,)))(b, xs, ys, c)
+    for key in want:
+        np.testing.assert_allclose(np.asarray(got[key]), np.asarray(want[key]), rtol=1e-5, atol=1e-6)

@@ -82,6 +82,38 @@ def test_lp_recovery_band_is_minimal():
     assert (res.b_full >= 0).all()
 
 
+@pytest.mark.parametrize("s,ell", [(5, 1), (8, 2), (10, 4)])
+def test_cyclic_matches_its_definition(s, ell):
+    # Shard j → nodes {j, …, j+ell−1} mod s, written as the plain loop.
+    n = 37
+    want = np.zeros((s, n), dtype=np.uint8)
+    for j in range(n):
+        for r in range(ell):
+            want[(j + r) % s, j] = 1
+    np.testing.assert_array_equal(cyclic_assignment(n, s, ell).matrix, want)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_lp_recovery_matches_the_undeduplicated_lp(seed):
+    # lp_recovery keeps one constraint pair per distinct alive-replica set;
+    # the optimum must equal the LP over every shard's constraints.
+    from scipy.optimize import linprog
+
+    rng = np.random.default_rng(seed)
+    a = bernoulli_assignment(200, 10, ell=3.0, rng=rng)
+    alive = random_stragglers(10, 0.3, rng)
+    A = a.matrix[alive].astype(np.float64)
+    A = A[:, A.sum(axis=0) > 0]
+    r, m = A.shape
+    A_ub = np.block([[-A.T, np.zeros((m, 1))], [A.T, -np.ones((m, 1))]])
+    b_ub = np.concatenate([-np.ones(m), np.zeros(m)])
+    c = np.zeros(r + 1)
+    c[-1] = 1.0
+    full = linprog(c, A_ub=A_ub, b_ub=b_ub, bounds=[(0, None)] * r + [(1.0, None)], method="highs")
+    res = lp_recovery(a, alive)
+    assert res.delta == pytest.approx(full.x[-1] - 1.0, abs=1e-7)
+
+
 def test_uniform_recovery_matches_paper_form():
     rng = np.random.default_rng(1)
     n, s, p_t, delta = 2000, 50, 0.1, 0.5

@@ -196,7 +196,7 @@ def test_deferred_under_trace_returns_default_uncached(monkeypatch):
     def resolve(_x):
         picks.append(autotune.tuned_strategy(
             "trace_op", (64, 64), jnp.float32, default="a",
-            candidates=("a", "b"), bench=bench,
+            candidates=("a", "b"), bench=bench, inputs=(_x,),
         ))
         return _x
 

@@ -168,11 +168,13 @@ def test_autotune_defers_under_trace_and_measures_eagerly(monkeypatch):
 def test_pick_blocks_respects_vmem_budget():
     for n, k, d in [(10_000, 4096, 8), (512, 64, 4096), (100, 7, 16), (1, 1, 1)]:
         cfg = dispatch.pick_blocks(n, k, d)
-        assert cfg.bn >= 8 and cfg.bk >= 8
-        assert (cfg.bn * d + cfg.bk * d + cfg.bn * cfg.bk) * 4 <= max(
+        # TPU tiling: every block is a whole number of 128-wide lanes.
+        assert cfg.bn % dispatch.LANE == 0 and cfg.bk % dispatch.LANE == 0
+        assert cfg.bn >= dispatch.LANE and cfg.bk >= dispatch.LANE
+        assert dispatch.block_footprint(cfg.bn, cfg.bk, d) <= max(
             dispatch.VMEM_BUDGET,
-            # floor: the minimum 8×8 tile may exceed the budget for huge d
-            (8 * d + 8 * d + 64) * 4,
+            # floor: the minimum 128×128 tile may exceed the budget for huge d
+            dispatch.block_footprint(dispatch.LANE, dispatch.LANE, d),
         )
 
 

@@ -90,8 +90,8 @@ def test_collectives_counted_with_loop_multiplier():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.launch.hlo_analysis import analyze_hlo
-        from repro.launch.compat import make_auto_mesh, shard_map
-        mesh = make_auto_mesh((4,), ("d",))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4,), ("d",), axis_types=(AxisType.Auto,))
 
         def f(x):
             def body(c, _):
@@ -100,7 +100,7 @@ def test_collectives_counted_with_loop_multiplier():
             y, _ = jax.lax.scan(body, x, None, length=5)
             return y
 
-        g = shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P("d"))
+        g = jax.shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P("d"))
         hlo = jax.jit(g).lower(jax.ShapeDtypeStruct((8, 16), jnp.float32)).compile().as_text()
         a = analyze_hlo(hlo)
         print(json.dumps({"coll": a["collective_bytes"], "ops": a["collective_ops"]}))

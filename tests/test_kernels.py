@@ -30,7 +30,7 @@ def test_pairwise_sqdist_kernel_sweep(n, k, d, dtype):
     rng = np.random.default_rng(n + k + d)
     x = jnp.asarray(rng.normal(size=(n, d)), dtype)
     c = jnp.asarray(rng.normal(size=(k, d)), dtype)
-    got = pd_kernel.pairwise_sqdist_kernel_call(x, c, bn=128, bk=128)
+    got = pd_kernel.pairwise_sqdist_kernel_call(x, c, bn=128, bk=128, interpret=True)
     want = pd_ref.pairwise_sqdist_ref(x, c)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=tol, atol=tol * 10)
@@ -41,7 +41,7 @@ def test_assign_min_kernel_sweep(n, k, d):
     rng = np.random.default_rng(7 * n + k)
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
     c = jnp.asarray(rng.normal(size=(k, d)), jnp.float32)
-    idx, dist = pd_kernel.assign_min_kernel_call(x, c, bn=128, bk=128)
+    idx, dist = pd_kernel.assign_min_kernel_call(x, c, bn=128, bk=128, interpret=True)
     iref, dref = pd_ref.assign_min_ref(x, c)
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(iref))
     np.testing.assert_allclose(np.asarray(dist), np.asarray(dref), rtol=2e-5, atol=2e-4)
@@ -73,6 +73,20 @@ def test_pairwise_ops_property(n, k, d):
     assert (np.asarray(got) >= 0).all()  # invariant: squared distances
 
 
+@pytest.mark.parametrize("n,k,d", [(300, 200, 16), (1000, 37, 5)])
+def test_assign_min_pallas_under_vmap(n, k, d):
+    # LocalExecutor vmaps every local solve over the node axis: the kernel's
+    # lane-dense (1, n) rows must stay right with the extra batch grid axis.
+    rng = np.random.default_rng(n + k)
+    x = jnp.asarray(rng.normal(size=(3, n, d)), jnp.float32)
+    c = jnp.asarray(rng.normal(size=(k, d)), jnp.float32)
+    idx, dist = jax.vmap(lambda a: pd_ops.assign_min(a, c, impl="pallas_interpret"))(x)
+    for b in range(3):
+        iref, dref = pd_ref.assign_min_ref(x[b], c)
+        np.testing.assert_array_equal(np.asarray(idx[b]), np.asarray(iref))
+        np.testing.assert_allclose(np.asarray(dist[b]), np.asarray(dref), rtol=2e-5, atol=2e-4)
+
+
 # ---------------------------------------------------------------- segsum
 
 
@@ -82,10 +96,35 @@ def test_weighted_segsum_kernel_sweep(n, k, d):
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
     w = jnp.asarray(rng.random(n), jnp.float32)
     idx = jnp.asarray(rng.integers(0, k, n), jnp.int32)
-    s_got, t_got = ss_kernel.weighted_segsum_kernel_call(x, w, idx, k, bn=256)
+    s_got, t_got = ss_kernel.weighted_segsum_kernel_call(x, w, idx, k, bn=256, interpret=True)
     s_ref, t_ref = ss_ref.weighted_segsum_ref(x, w, idx, k)
     np.testing.assert_allclose(np.asarray(s_got), np.asarray(s_ref), rtol=2e-5, atol=1e-3)
     np.testing.assert_allclose(np.asarray(t_got), np.asarray(t_ref), rtol=2e-5, atol=1e-4)
+
+
+def test_bf16_terms_carry_every_f32_bit():
+    # The segment-sum kernel's f32 sums rest on this: three bf16 terms add
+    # back to the f32 value exactly, across magnitudes.
+    rng = np.random.default_rng(5)
+    v = (rng.standard_normal(4096) * 10.0 ** rng.integers(-6, 7, 4096)).astype(np.float32)
+    hi, mid, lo = ss_kernel._bf16_terms(jnp.asarray(v))
+    back = hi.astype(jnp.float32) + mid.astype(jnp.float32) + lo.astype(jnp.float32)
+    np.testing.assert_array_equal(np.asarray(back), v)
+    assert not np.array_equal(np.asarray(hi.astype(jnp.float32)), v)  # bf16 alone rounds
+
+
+def test_weighted_segsum_pallas_under_vmap():
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(size=(3, 300, 16)), jnp.float32)
+    w = jnp.asarray(rng.random((3, 300)), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, 37, (3, 300)), jnp.int32)
+    sums, tot = jax.vmap(
+        lambda a, ww, ii: ss_ops.weighted_segsum(a, ww, ii, 37, impl="pallas_interpret")
+    )(x, w, idx)
+    for b in range(3):
+        s_ref, t_ref = ss_ref.weighted_segsum_ref(x[b], w[b], idx[b], 37)
+        np.testing.assert_allclose(np.asarray(sums[b]), np.asarray(s_ref), rtol=2e-5, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(tot[b]), np.asarray(t_ref), rtol=2e-5, atol=1e-5)
 
 
 def test_weighted_segsum_mass_conservation():
@@ -114,6 +153,26 @@ def test_flash_pallas_vs_ref(B, T, H, KV, dh, causal):
     got = fa_ops.flash_attention(q, k, v, causal=causal, impl="pallas")
     want = fa_ref.attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-4)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_pallas_grad_matches_ref(causal):
+    # The kernel has no backward of its own; its custom VJP must give the
+    # reference attention's gradient, which is what a train step uses.
+    rng = np.random.default_rng(13)
+    q = jnp.asarray(rng.normal(size=(2, 128, 4, 32)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(2, 128, 2, 32)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(2, 128, 2, 32)), jnp.float32)
+
+    def loss(impl):
+        return lambda q, k, v: jnp.sum(
+            jnp.sin(fa_ops.flash_attention(q, k, v, causal=causal, impl=impl))
+        )
+
+    got = jax.grad(loss("pallas"), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss("ref"), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

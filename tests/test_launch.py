@@ -55,10 +55,10 @@ def test_train_lowering_single_and_multipod_mini():
     meshes; collectives exist; the loop-aware analysis sees the layer scan."""
     code = _PRELUDE + textwrap.dedent(
         """
-        from repro.launch.compat import make_auto_mesh
+        from jax.sharding import AxisType
         for shape, axes in (((2, 4), ("data", "model")),
                             ((2, 2, 2), ("pod", "data", "model"))):
-            mesh = make_auto_mesh(shape, axes)
+            mesh = jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
             ctx = make_context(mesh, attn_impl="chunked", remat="full")
             state_struct = jax.eval_shape(
                 lambda _: init_train_state(jax.random.PRNGKey(0), cfg), 0)
@@ -87,8 +87,8 @@ def test_train_lowering_single_and_multipod_mini():
 def test_decode_lowering_with_cache_shardings():
     code = _PRELUDE + textwrap.dedent(
         """
-        from repro.launch.compat import make_auto_mesh
-        mesh = make_auto_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         ctx = make_context(mesh, attn_impl="chunked")
         B, S = 8, 128
         params_struct = jax.eval_shape(
@@ -120,8 +120,8 @@ def test_sharding_rules_divisibility_fallback():
         import jax
         from jax.sharding import PartitionSpec as P
         from repro.launch.sharding import param_spec
-        from repro.launch.compat import make_auto_mesh
-        mesh = make_auto_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         # 14*64=896-wide head projection: 896 % 4 == 0 → tp applies on dim 1;
         # but a 14-wide bias does not divide 4 → replicated.
         s1 = param_spec("unit/slot0/attn/wq", (128, 896), mesh)
@@ -145,8 +145,8 @@ def test_moe_local_routing_matches_pjit_routing():
         from repro.configs.deepseek_moe_16b import smoke_config
         from repro.models import moe as M
         cfg = smoke_config().validate()
-        from repro.launch.compat import make_auto_mesh
-        mesh = make_auto_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         params = M.moe_init(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model), jnp.float32)
         kw = dict(mesh=mesh, batch_axes=("data",), model_axis="model", fsdp_axis="data")
@@ -175,8 +175,8 @@ def test_moe_shard_map_lowering_mini():
         from repro.launch.sharding import make_context, param_shardings
         from repro.models import moe as M
         cfg = smoke_config().validate()
-        from repro.launch.compat import make_auto_mesh
-        mesh = make_auto_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         ctx = make_context(mesh)
         params = jax.eval_shape(lambda _: M.moe_init(jax.random.PRNGKey(0), cfg), 0)
         p_sh = param_shardings({"moe": params}, mesh)["moe"]

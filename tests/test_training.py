@@ -369,6 +369,25 @@ def test_device_recovery_no_recompile_across_patterns(cfg):
     assert t.plan.session.stats.host_solves == 0
 
 
+def test_device_recovery_run_consumes_the_passed_state(cfg):
+    """On the fused path the update donates its state: ``run(state)`` deletes
+    the caller's buffers (the warm-up does not), and the returned state is
+    the one to keep."""
+    tc = TrainerConfig(
+        num_groups=4, num_shards=4, redundancy=2, scheme="fr",
+        microbatch=1, seq_len=32, steps=2, simulate_stragglers=False,
+        device_recovery=True, resident_steps=2,
+    )
+    t = Trainer(cfg, tc, AdamWConfig(lr=5e-3, warmup_steps=2, total_steps=2))
+    state, _ = t.init_state()
+    leaf = jax.tree_util.tree_leaves(state.params)[0]
+    t.warmup(state)
+    assert not leaf.is_deleted(), "the warm-up consumed the caller's state"
+    out = t.run(state)
+    assert leaf.is_deleted(), "run() kept the donated state alive"
+    assert all(np.isfinite(np.asarray(x)).all() for x in jax.tree_util.tree_leaves(out.params))
+
+
 def test_device_recovery_degenerate_pattern_falls_back(cfg):
     """A pattern that loses a shard entirely (singleton scheme, one dead
     group) must take the host best-effort path — the step still applies an
