@@ -1,0 +1,8 @@
+"""Share of the chip's busy time spent in ``assign_min``."""
+
+
+def read(ctx):
+    km = ctx["kernels"]("assign_min")
+    took = sum(s for name, s in ctx["trace"]["op_seconds"].items() if km.matches(name))
+    busy = ctx["trace"]["busy_s"]
+    return 100.0 * took / busy if took > 0 and busy > 0 else None
