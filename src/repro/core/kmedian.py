@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import trace_span
 from . import kmeans
 from .assignment import Assignment
 from .executor import Executor, get_executor
@@ -184,28 +185,40 @@ def _coordinator_pipeline(
     impl: str,
 ) -> tuple[np.ndarray, float, np.ndarray, np.ndarray]:
     """Shared steps 2–3: local solves (via executor), b-weighted fixed-shape
-    union, coordinator weighted k-median, full-dataset cost."""
-    s, _, d = xs.shape
-    keys = jax.random.split(jax.random.PRNGKey(seed), s)
-    fn = _local_solve_fn(k, local_iters, True, impl)
-    centers_s, wts_s = ex.map_nodes(
-        fn,
-        (keys, jnp.asarray(xs), jnp.asarray(ws), jnp.asarray(b_full, jnp.float32)),
-    )
-    # Fixed-shape union: (s·k, d) points, b-weighted weights (0 at stragglers
-    # — inert in the weighted coordinator solve, like in-shard padding rows).
-    y = np.asarray(centers_s).reshape(s * k, d)
-    wy = np.asarray(wts_s).reshape(s * k)
-    res = kmeans.lloyd(
-        jax.random.PRNGKey(seed + 1), jnp.asarray(y), k, weights=jnp.asarray(wy),
-        iters=coord_iters, median=True, impl=impl,
-    )
-    centers = np.asarray(res.centers)
-    full_cost = float(
-        kmeans.clustering_cost(
-            jnp.asarray(points), jnp.asarray(centers), median=True, impl=impl
+    union, coordinator weighted k-median, full-dataset cost.
+
+    Four sibling spans: ``kmedian.upload`` (the packed shards handed to the
+    device), then ``kmedian.local``, ``kmedian.coordinator`` and
+    ``kmedian.cost``, each ending where its result is on the host."""
+    s, m, d = xs.shape
+    with trace_span("kmedian.upload", rows=s * m, bytes=xs.nbytes + ws.nbytes):
+        # Not waited for: the span ends once the copy is handed to the
+        # runtime, and the rest of the transfer shows under kmedian.local,
+        # where the local solves wait for it.  A wait here would only keep
+        # the host from dispatching them meanwhile.
+        xs_d, ws_d = jnp.asarray(xs), jnp.asarray(ws)
+        b_d = jnp.asarray(b_full, jnp.float32)
+    with trace_span("kmedian.local", nodes=s, rows=s * m):
+        keys = jax.random.split(jax.random.PRNGKey(seed), s)
+        fn = _local_solve_fn(k, local_iters, True, impl)
+        centers_s, wts_s = ex.map_nodes(fn, (keys, xs_d, ws_d, b_d))
+        # Fixed-shape union: (s·k, d) points, b-weighted weights (0 at
+        # stragglers — inert in the weighted coordinator solve, like
+        # in-shard padding rows).
+        y = np.asarray(centers_s).reshape(s * k, d)
+        wy = np.asarray(wts_s).reshape(s * k)
+    with trace_span("kmedian.coordinator", rows=s * k):
+        res = kmeans.lloyd(
+            jax.random.PRNGKey(seed + 1), jnp.asarray(y), k, weights=jnp.asarray(wy),
+            iters=coord_iters, median=True, impl=impl,
         )
-    )
+        centers = np.asarray(res.centers)
+    with trace_span("kmedian.cost", rows=points.shape[0]):
+        full_cost = float(
+            kmeans.clustering_cost(
+                jnp.asarray(points), jnp.asarray(centers), median=True, impl=impl
+            )
+        )
     return centers, full_cost, y, wy
 
 

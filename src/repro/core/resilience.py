@@ -40,7 +40,7 @@ from typing import Optional, Union
 import numpy as np
 
 from ..analysis import compiled_path
-from ..obs import StatsView, default_registry, trace_span
+from ..obs import StatsView, default_registry, install_pause_hooks, trace_span
 from .assignment import Assignment, cyclic_assignment
 from .executor import Executor, get_executor
 from .placement import PlacementOptimizer
@@ -138,6 +138,7 @@ class ResilienceSession:
         self.placement: Optional[PlacementOptimizer] = placement or None
         self._obs_labels = {"session": f"s{next(_SESSION_IDS)}"}
         self.stats = SessionStats(labels=self._obs_labels)
+        install_pause_hooks()
         self.version = 0  # bumped by every elastic patch
         # Object ids of every assignment this session has owned (the original
         # plus each elastic patch) — lets entry points reject a genuinely
@@ -288,50 +289,63 @@ class ResilienceSession:
     def _fingerprint(points) -> bytes:
         """Cheap content hash: identity alone would serve stale packs after
         an in-place mutation of the caller's array (pts *= 0.5)."""
-        a = np.ascontiguousarray(np.asarray(points))
-        h = hashlib.blake2b(digest_size=16)
-        h.update(str((a.shape, a.dtype.str)).encode())
-        h.update(a.tobytes())
-        return h.digest()
+        with trace_span("session.fingerprint") as sp:
+            a = np.ascontiguousarray(np.asarray(points))
+            h = hashlib.blake2b(digest_size=16)
+            h.update(str((a.shape, a.dtype.str)).encode())
+            h.update(a.tobytes())
+            sp.set_attr(bytes=a.nbytes)
+            return h.digest()
 
-    def _packed_shards(self, points, fp: Optional[bytes] = None):
-        fp = self._fingerprint(points) if fp is None else fp
-        if self._packed is not None and self._pack_src is points and (
+    def _packed_shards(self, points):
+        with trace_span("session.pack", **self._obs_labels) as sp:
+            return self._pack(points, self._fingerprint(points), sp)
+
+    def _pack(self, points, fp: bytes, sp):
+        """The host pack for ``points`` (fingerprint ``fp``), cached per
+        points object, content and assignment version; ``sp`` (the
+        enclosing ``session.pack`` span) is told rows, bytes and hit."""
+        hit = self._packed is not None and self._pack_src is points and (
             self._pack_version == self.version and self._pack_fp == fp
-        ):
-            return self._packed_pts, *self._packed
-        from .kmedian import pack_local_shards
+        )
+        if not hit:
+            from .kmedian import pack_local_shards
 
-        pts32 = np.asarray(points, dtype=np.float32)
-        xs, ws = pack_local_shards(pts32, self.assignment)
-        self._pack_src = points
-        self._pack_fp = fp
-        self._packed_pts = pts32
-        self._packed = (xs, ws)
-        self._pack_version = self.version
-        return pts32, xs, ws
+            pts32 = np.asarray(points, dtype=np.float32)
+            xs, ws = pack_local_shards(pts32, self.assignment)
+            self._pack_src = points
+            self._pack_fp = fp
+            self._packed_pts = pts32
+            self._packed = (xs, ws)
+            self._pack_version = self.version
+        xs, ws = self._packed
+        sp.set_attr(hit=hit, rows=xs.shape[0] * xs.shape[1], bytes=xs.nbytes + ws.nbytes)
+        return self._packed_pts, xs, ws
 
     # ------------------------------------------------ fused on-device path
 
     def _ensure_resident(self, points):
-        fp = self._fingerprint(points)
-        if self._resident is not None and (
-            self._resident_version == self.version
-            and self._resident_src is points
-            and self._resident_fp == fp
-        ):
+        with trace_span("session.pack", resident=True, **self._obs_labels) as sp:
+            fp = self._fingerprint(points)
+            if self._resident is not None and (
+                self._resident_version == self.version
+                and self._resident_src is points
+                and self._resident_fp == fp
+            ):
+                sp.set_attr(hit=True)
+                return self._resident
+            _, xs, ws = self._pack(points, fp, sp)
+            sp.set_attr(placed=True)
+            ex = self.executor
+            self._resident = (
+                ex.place_node_stacked(xs),
+                ex.place_node_stacked(ws),
+                ex.place_broadcast(self.assignment.matrix.astype(np.float32)),
+            )
+            self._resident_src = points
+            self._resident_fp = fp
+            self._resident_version = self.version
             return self._resident
-        _, xs, ws = self._packed_shards(points, fp)
-        ex = self.executor
-        self._resident = (
-            ex.place_node_stacked(xs),
-            ex.place_node_stacked(ws),
-            ex.place_broadcast(self.assignment.matrix.astype(np.float32)),
-        )
-        self._resident_src = points
-        self._resident_fp = fp
-        self._resident_version = self.version
-        return self._resident
 
     @compiled_path("session.step_cost", kind="host")
     def step_cost(

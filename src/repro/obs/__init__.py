@@ -2,7 +2,9 @@
 
 One process-wide :class:`MetricsRegistry` (counters / gauges / log-bucket
 histograms, Prometheus text dump) and one :func:`trace_span` API (nested
-host-side spans, JSONL ring-buffer export).  Every tier — resilience
+host-side spans, JSONL ring-buffer export, started on the profiler's
+clock) with host pauses — collections, XLA compiles, persistent-cache loads
+— recorded as spans too (:func:`install_pause_hooks`).  Every tier — resilience
 sessions, executors, serving, streaming, training, autotune — records
 through here; ``tools/obs_report.py`` / ``make obs-report`` renders both.
 
@@ -22,12 +24,14 @@ from .metrics import (
     percentile,
     set_default_registry,
 )
+from .pauses import install_pause_hooks
 from .trace import (
     Span,
     TraceBuffer,
     configure_buffer,
     default_buffer,
     export_jsonl,
+    flush,
     obs_enabled,
     profiler_enabled,
     set_clock,
@@ -47,6 +51,8 @@ __all__ = [
     "default_buffer",
     "default_registry",
     "export_jsonl",
+    "flush",
+    "install_pause_hooks",
     "log_bounds",
     "obs_enabled",
     "percentile",

@@ -1,19 +1,29 @@
 """Structured tracing: nested host-side spans over the compiled tiers.
 
 A *span* is one timed host-side operation — a serve dispatch, a recovery
-solve, a streaming compaction, an autotune measurement pass — with monotonic
-start/end timestamps, a parent (spans nest through a ``contextvars`` stack,
+solve, a streaming compaction, an autotune measurement pass — with a start,
+a monotonic duration, a parent (spans nest through a ``contextvars`` stack,
 so the tree is correct under asyncio interleaving and threads), and a small
 attribute dict (``tenant=…, node=…, shard=…, pattern=…``).
 
 Spans wrap compiled-step *invocations* and never run inside them: all of
 this is plain host Python, recorded only where the repo already crosses the
 host↔device boundary.  Finished spans land in a process-wide fixed-capacity
-ring buffer (:class:`TraceBuffer`; ``REPRO_OBS_BUFFER`` rows, default 4096 —
+ring buffer (:class:`TraceBuffer`; ``REPRO_OBS_BUFFER`` rows, default 16384 —
 overflow evicts the oldest and is counted, never grows) and export as JSONL
 (:func:`export_jsonl`) for offline timeline assembly; each span also feeds
 the ``obs_span_us{name=…}`` histogram in the default metrics registry so
 ``obs-report`` shows latency distributions without replaying the trace.
+
+One clock with the profiler: ``jax.profiler`` stamps its host events with
+the wall clock (``CLOCK_REALTIME``, ns since the epoch; an ``.xplane.pb``
+stores them relative to its ``profile_start_time``).  A span measures its
+duration on the monotonic clock and reports its start (``ts``, seconds) on
+that wall clock, through an offset between the two taken once at import, so
+a ring row can be put against the device trace.  Host pauses that no
+``with`` block can wrap — collections, XLA compiles, persistent-cache loads
+— are recorded as spans by :mod:`repro.obs.pauses` through
+:func:`record_span` / :func:`defer_span`.
 
 Gating: ``REPRO_OBS=0`` disables span recording (counters stay on — they are
 the tiers' stats objects).  ``REPRO_OBS_PROFILER=1`` additionally brackets
@@ -21,12 +31,14 @@ every span in a ``jax.profiler.TraceAnnotation`` so spans line up with XLA
 activity in a profiler trace viewer.
 
 The clock is a module seam (:func:`set_clock`) mirroring the serving tier's
-``VirtualClock`` pattern: the span-tree tests drive a fake monotonic clock
-and assert exact timestamps — zero sleeps.
+``VirtualClock`` pattern: the span-tree tests drive a fake clock (whose
+readings are reported as they are, with no offset) and assert exact
+timestamps — zero sleeps.
 """
 
 from __future__ import annotations
 
+import collections
 import contextvars
 import itertools
 import json
@@ -44,6 +56,7 @@ __all__ = [
     "configure_buffer",
     "default_buffer",
     "export_jsonl",
+    "flush",
     "obs_enabled",
     "profiler_enabled",
     "set_clock",
@@ -55,7 +68,7 @@ BUFFER_ENV = "REPRO_OBS_BUFFER"        # ring capacity (rows)
 PROFILER_ENV = "REPRO_OBS_PROFILER"    # opt-IN: jax.profiler annotations
 
 _OFF_VALUES = ("0", "off", "false", "no", "none")
-DEFAULT_BUFFER_ROWS = 4096
+DEFAULT_BUFFER_ROWS = 16384
 
 # Latency spans span ~µs (cache hit) to ~minutes (mesh solve): µs-resolution
 # log buckets, one shared shape for every obs_span_us series.
@@ -79,14 +92,30 @@ def _buffer_rows() -> int:
         return DEFAULT_BUFFER_ROWS
 
 
-# Monotonic clock seam (tests swap in a fake; see module docstring).
-_clock: Callable[[], float] = time.perf_counter
+def _wall_offset() -> float:
+    """Seconds to add to a ``time.perf_counter`` reading to read the
+    profiler's host clock (the wall clock), from one wall reading bracketed
+    by two monotonic ones."""
+    a = time.perf_counter()
+    wall = time.time_ns() * 1e-9
+    b = time.perf_counter()
+    return wall - 0.5 * (a + b)
+
+
+# Monotonic clock seam (tests swap in a fake; see module docstring), and the
+# offset that puts its readings on the profiler's clock.
+_DEFAULT_CLOCK: Callable[[], float] = time.perf_counter
+_WALL_OFFSET = _wall_offset()
+_clock: Callable[[], float] = _DEFAULT_CLOCK
+_offset = _WALL_OFFSET
 
 
 def set_clock(clock: Callable[[], float]) -> Callable[[], float]:
-    """Swap the span clock; returns the previous one (restore in teardown)."""
-    global _clock
+    """Swap the span clock; returns the previous one (restore in teardown).
+    Any clock but the default reports its readings as they are."""
+    global _clock, _offset
     prev, _clock = _clock, clock
+    _offset = _WALL_OFFSET if clock is _DEFAULT_CLOCK else 0.0
     return prev
 
 
@@ -125,14 +154,20 @@ class Span:
         return (end - self.t_start) * 1e6
 
     def as_dict(self) -> dict:
-        return {
-            "name": self.name,
-            "span": self.span_id,
-            "parent": self.parent_id,
-            "ts": self.t_start,
-            "dur_us": self.duration_us,
-            "attrs": self.attrs,
-        }
+        return _row(self.name, self.span_id, self.parent_id, self.t_start,
+                    self.duration_us, self.attrs)
+
+
+def _row(name, span_id, parent_id, t_start, dur_us, attrs) -> dict:
+    """One ring row; ``ts`` is the start on the profiler's clock."""
+    return {
+        "name": name,
+        "span": span_id,
+        "parent": parent_id,
+        "ts": t_start + _offset,
+        "dur_us": dur_us,
+        "attrs": attrs,
+    }
 
 
 class _NullSpan:
@@ -215,6 +250,73 @@ class TraceBuffer:
 
 _BUFFER = TraceBuffer()
 
+# ``obs_span_us{name=…}`` handles by span name, for the registry they were
+# resolved in (tests swap the registry): a span exit is then a dict hit, not
+# a registry lookup (a label sort and a lock).
+_hists: dict = {}
+_hists_registry = None
+
+
+def _span_hist(name: str):
+    global _hists_registry
+    reg = default_registry()
+    if reg is not _hists_registry:
+        _hists.clear()
+        _hists_registry = reg
+    h = _hists.get(name)
+    if h is None:
+        h = _hists[name] = reg.histogram(
+            "obs_span_us", labels={"name": name}, bounds=SPAN_BOUNDS,
+            help="span durations by name (µs)",
+        )
+    return h
+
+
+def _finish(row: dict) -> None:
+    _BUFFER.record(row)
+    _span_hist(row["name"]).observe(row["dur_us"])
+
+
+def record_span(
+    name: str,
+    t_start: float,
+    t_end: float,
+    attrs: Optional[dict] = None,
+    *,
+    parent_id: Optional[int] = None,
+    span_id: Optional[int] = None,
+) -> None:
+    """Record a span timed elsewhere — a host pause a hook saw after it
+    happened.  ``t_start``/``t_end`` are readings of the span clock
+    (``_clock``)."""
+    _finish(_row(
+        name, next(_span_ids) if span_id is None else span_id, parent_id,
+        t_start, (t_end - t_start) * 1e6, attrs if attrs is not None else {},
+    ))
+
+
+# Spans seen where no lock may be taken: a collector callback can run while
+# this very thread holds the ring's or a histogram's lock.  Queued as
+# ``record_span`` arguments; every span exit records them (:func:`flush`).
+# Bounded like the ring: a long stretch with no span exit keeps the newest.
+_deferred: collections.deque = collections.deque(maxlen=DEFAULT_BUFFER_ROWS)
+
+
+def defer_span(name: str, t_start: float, t_end: float, attrs: dict,
+               parent_id: Optional[int] = None) -> None:
+    """Queue a finished span for :func:`flush`; takes no lock."""
+    _deferred.append((name, t_start, t_end, attrs, parent_id))
+
+
+def flush() -> None:
+    """Record every span queued by :func:`defer_span`."""
+    while _deferred:
+        try:
+            name, t0, t1, attrs, parent = _deferred.popleft()
+        except IndexError:  # another thread took the last one
+            return
+        record_span(name, t0, t1, attrs, parent_id=parent)
+
 
 def default_buffer() -> TraceBuffer:
     """The process-wide span ring ``trace_span`` records into."""
@@ -289,9 +391,9 @@ class trace_span:
         span.t_end = _clock()
         if exc_type is not None:
             span.attrs.setdefault("error", exc_type.__name__)
-        _BUFFER.record(span.as_dict())
-        default_registry().histogram(
-            "obs_span_us", labels={"name": span.name}, bounds=SPAN_BOUNDS,
-            help="span durations by name (µs)",
-        ).observe(span.duration_us)
+        # Queued spans ended before this one: record them first, so that a
+        # burst of them cannot push this span out of a small ring.
+        if _deferred:
+            flush()
+        _finish(span.as_dict())
         return False

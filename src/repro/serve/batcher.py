@@ -47,6 +47,10 @@ class Ticket:
     result: object = None                     # QueryResult once done
     error: Optional[str] = None               # reason once rejected
     from_cache: bool = False
+    # Frontend-clock stamps: when a dispatch took the ticket's batch (None
+    # for a cache hit, which no dispatch takes) and when its answer was set.
+    dispatched_at: Optional[float] = None
+    completed_at: Optional[float] = None
     # Completion hook for the async shell; called exactly once with the
     # ticket after it leaves PENDING.  The sans-io core never awaits.
     waiter: Optional[Callable] = None

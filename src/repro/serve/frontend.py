@@ -50,7 +50,7 @@ import numpy as np
 from ..analysis import compiled_path
 from ..kernels import autotune
 from ..kernels.pairwise_dist import ops as pd
-from ..obs import default_registry, trace_span
+from ..obs import default_registry, install_pause_hooks, trace_span
 from ..stream.query import QueryResult, bucket_size
 from .batcher import Batch, MicroBatcher, Ticket
 from .cache import AssignmentCache
@@ -185,6 +185,13 @@ class ServingFrontend:
         self._c_dispatches = _counter("serve_dispatches", "compiled batch dispatches")
         self._c_warmups = _counter("serve_warmups", "warm-up passes (solves + explicit)")
         self._c_occupancy = _counter("serve_occupancy_sum", "Σ rows/padded-bucket per dispatch")
+        self._c_queue_wait = _counter(
+            "serve_queue_wait_seconds",
+            "Σ over dispatched tickets of dispatched_at − submitted_at (s)",
+        )
+        self._c_dispatched_tickets = _counter(
+            "serve_dispatched_tickets", "tickets taken by dispatches (rejects included)"
+        )
         # Admission rejections split by stage: a submit-time bounce is cheap
         # backpressure, a dispatch-time bounce wasted a batch slot.
         self._c_reject_stage = {
@@ -214,6 +221,7 @@ class ServingFrontend:
         # registry lookup (label-sort + lock) per completed ticket measured
         # as a double-digit-% serve p50 regression at burst size 512.
         self._lat_hists: Dict[str, object] = {}
+        install_pause_hooks()
 
     # ------------------------------------------------------------ tenants
 
@@ -339,6 +347,7 @@ class ServingFrontend:
             # a fresh dispatch would report right now, so the bound check
             # above already covers it.
             ticket.from_cache = True
+            ticket.completed_at = now
             ticket._complete(hit)
             state.queries_served += ticket.rows
             self._c_served.inc(ticket.rows)
@@ -381,6 +390,14 @@ class ServingFrontend:
         have run while tickets waited out the window, and a bound the
         submit-time check admitted can be violated by dispatch time.
         """
+        dispatched = self.clock.now()
+        wait = 0.0
+        for t in batch.tickets:
+            t.dispatched_at = dispatched
+            wait += t.dispatched_at - t.submitted_at
+        # One increment per dispatch, like every counter here.
+        self._c_queue_wait.inc(wait)
+        self._c_dispatched_tickets.inc(len(batch.tickets))
         state = self._tenants[batch.tenant]
         session = state.session
         centers = session.ensure_model()
@@ -411,28 +428,31 @@ class ServingFrontend:
             # on a device array is itself a traced op — one compile per
             # distinct row count and ~ms of dispatch per call, which profiled
             # as 6× the cost of the assignment itself.  Padding is a few KB.
-            idx_h, dist_h = jax.device_get((idx, dist))
-        idx_h = np.asarray(idx_h[:n], np.int32)
-        dist_h = np.asarray(dist_h[:n], np.float32)
-        generation = session.generation
-        version = session.version
-        offset = 0
-        done = self.clock.now()
-        lats = []
-        for t in live:
-            m = t.rows
-            result = QueryResult(
-                indices=idx_h[offset : offset + m],
-                distances=dist_h[offset : offset + m],
-                staleness_points=staleness["points"],
-                staleness_ingests=staleness["ingests"],
-                version=version,
-            )
-            offset += m
-            self.cache.put(self.cache.key(batch.tenant, generation, t.queries), result)
-            t._complete(result)
-            state.queries_served += m
-            lats.append((done - t.submitted_at) * 1e6)
+            with trace_span("serve.fetch", rows=n, bucket=bucket):
+                idx_h, dist_h = jax.device_get((idx, dist))
+        with trace_span("serve.complete", tickets=len(live)):
+            idx_h = np.asarray(idx_h[:n], np.int32)
+            dist_h = np.asarray(dist_h[:n], np.float32)
+            generation = session.generation
+            version = session.version
+            offset = 0
+            done = self.clock.now()
+            lats = []
+            for t in live:
+                m = t.rows
+                result = QueryResult(
+                    indices=idx_h[offset : offset + m],
+                    distances=dist_h[offset : offset + m],
+                    staleness_points=staleness["points"],
+                    staleness_ingests=staleness["ingests"],
+                    version=version,
+                )
+                offset += m
+                self.cache.put(self.cache.key(batch.tenant, generation, t.queries), result)
+                t.completed_at = done
+                t._complete(result)
+                state.queries_served += m
+                lats.append((t.completed_at - t.submitted_at) * 1e6)
         # Metric writes are batched — ONE counter inc and ONE histogram lock
         # per dispatch, not per ticket (per-ticket locking measured as a
         # serve p50 regression at burst size 512).

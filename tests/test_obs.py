@@ -9,6 +9,7 @@ scripts a straggler scenario and replays the recurrence by hand.
 
 from __future__ import annotations
 
+import gc
 import json
 import threading
 
@@ -21,6 +22,7 @@ from repro.obs import (
     StatsView,
     TraceBuffer,
     default_registry,
+    flush,
     log_bounds,
     percentile,
     set_clock,
@@ -44,9 +46,17 @@ class FakeClock:
 
 @pytest.fixture()
 def fresh_obs(monkeypatch):
-    """Fresh registry, fresh 64-row buffer, fake clock; all restored after."""
+    """Fresh registry, fresh 64-row buffer, fake clock; all restored after.
+
+    Automatic collections are off meanwhile, and the ``process.gc`` spans
+    queued before the test are recorded into the old buffer: with the pause
+    hooks installed by another test in this process, a collection would
+    otherwise land a span in the middle of an exact span-tree assertion."""
     monkeypatch.delenv("REPRO_OBS", raising=False)
     monkeypatch.delenv("REPRO_OBS_PROFILER", raising=False)
+    gc_was_enabled = gc.isenabled()
+    gc.disable()
+    flush()
     prev_reg = set_default_registry(MetricsRegistry())
     prev_buf = trace_mod._BUFFER
     buf = trace_mod.configure_buffer(64)
@@ -56,6 +66,8 @@ def fresh_obs(monkeypatch):
     set_clock(prev_clock)
     trace_mod._BUFFER = prev_buf
     set_default_registry(prev_reg)
+    if gc_was_enabled:
+        gc.enable()
 
 
 # --------------------------------------------------------------- percentile
@@ -392,3 +404,116 @@ def test_summary_lines_and_write_report(fresh_obs, tmp_path):
     write_report(str(tmp_path), reg, buf)
     rows = [json.loads(l) for l in open(trace_path, encoding="utf-8")]
     assert len(rows) == 1
+
+
+# ------------------------------------------------------------- host pauses
+
+
+def test_forced_collection_records_one_process_gc_span(fresh_obs):
+    from repro.obs import install_pause_hooks
+
+    _, buf, _ = fresh_obs
+    install_pause_hooks()
+    with trace_span("outer") as outer:
+        gc.collect()  # the fixture turned automatic collection off
+    rows = [r for r in buf.rows() if r["name"] == "process.gc"]
+    assert len(rows) == 1
+    assert rows[0]["attrs"] == {"generation": 2}
+    assert rows[0]["parent"] == outer.span_id
+
+
+def test_queued_pauses_cannot_push_the_exiting_span_out_of_the_ring(fresh_obs):
+    _, buf, _ = fresh_obs
+    for i in range(3 * buf.capacity):
+        trace_mod.defer_span("process.gc", i, i, {"generation": 0})
+    with trace_span("after.pauses"):
+        pass
+    rows = buf.rows()
+    assert len(rows) == buf.capacity
+    assert rows[-1]["name"] == "after.pauses"
+    assert all(r["name"] == "process.gc" for r in rows[:-1])
+
+
+def test_fresh_jit_records_one_jax_compile_span(fresh_obs):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.obs import install_pause_hooks
+
+    _, buf, _ = fresh_obs
+    install_pause_hooks()
+    x = jnp.arange(5.0)
+
+    def obs_fresh_compile_probe(v):
+        return v * 2.0 + 1.0
+
+    with trace_span("outer") as outer:
+        jax.jit(obs_fresh_compile_probe)(x).block_until_ready()
+    rows = [r for r in buf.rows() if r["name"] == "jax.compile"
+            and "obs_fresh_compile_probe" in r["attrs"]["fun_name"]]
+    assert len(rows) == 1
+    assert rows[0]["parent"] == outer.span_id
+    # Timed by the event's own start and end, not by the (frozen) fake clock.
+    assert rows[0]["dur_us"] > 0.0
+
+
+def test_cache_load_is_the_child_of_its_compile(fresh_obs):
+    import jax.monitoring as monitoring
+
+    from repro.obs import install_pause_hooks
+    from repro.obs import pauses
+
+    _, buf, clock = fresh_obs
+    install_pause_hooks()
+    t0 = 1000.0
+    monitoring.record_scalar(pauses.COMPILE_EVENT, t0, fun_name="jit(f)")
+    monitoring.record_event_duration_secs(pauses.CACHE_LOAD_EVENT, 0.25)
+    monitoring.record_event_time_span(pauses.COMPILE_EVENT, t0, t0 + 0.5, fun_name="jit(f)")
+    by_name = {r["name"]: r for r in buf.rows()}
+    load, comp = by_name["jax.cache_load"], by_name["jax.compile"]
+    assert load["parent"] == comp["span"]
+    assert load["dur_us"] == pytest.approx(250000.0)
+    assert load["ts"] == pytest.approx(clock.t - 0.25)
+    assert comp["dur_us"] == pytest.approx(500000.0)
+    assert comp["attrs"] == {"fun_name": "jit(f)"}
+
+
+def test_span_exit_reuses_its_cached_histogram(fresh_obs, monkeypatch):
+    reg, _, _ = fresh_obs
+    resolved = []
+    histogram = reg.histogram
+
+    def counting(name, *a, **kw):
+        resolved.append((name, kw.get("labels")))
+        return histogram(name, *a, **kw)
+
+    monkeypatch.setattr(reg, "histogram", counting)
+    for _ in range(3):
+        with trace_span("cached.one"):
+            pass
+    assert resolved == [("obs_span_us", {"name": "cached.one"})]
+    assert histogram("obs_span_us", labels={"name": "cached.one"},
+                     bounds=trace_mod.SPAN_BOUNDS).count == 3
+    # A registry swapped in afterwards gets handles of its own.
+    set_default_registry(MetricsRegistry())
+    with trace_span("cached.one"):
+        pass
+    assert default_registry().histogram(
+        "obs_span_us", labels={"name": "cached.one"}, bounds=trace_mod.SPAN_BOUNDS
+    ).count == 1
+
+
+def test_default_clock_reports_starts_on_the_wall_clock(fresh_obs):
+    import time
+
+    _, buf, _ = fresh_obs
+    prev = set_clock(trace_mod._DEFAULT_CLOCK)
+    try:
+        before = time.time()
+        with trace_span("wall.probe"):
+            pass
+        after = time.time()
+    finally:
+        set_clock(prev)
+    (row,) = buf.rows()
+    assert before - 1e-3 <= row["ts"] <= after + 1e-3

@@ -258,6 +258,46 @@ def test_session_one_cache_across_algorithms_and_plan():
     assert sess.stats.cache_hits == 3
 
 
+def test_kmedian_span_tree_names_each_host_step():
+    """One solve records its pack (with the fingerprint inside it) and the
+    four pipeline steps as siblings, under no span that encloses the whole
+    solve; the second solve of the same array is a pack hit."""
+    from repro.core import ResilienceSession, cyclic_assignment
+    from repro.obs import configure_buffer
+    from repro.obs import trace as trace_mod
+
+    pts = _pts(96)
+    alive = np.array([True, False, True, True])
+    trace_mod.flush()  # pause spans queued by earlier tests go to the old ring
+    prev = trace_mod._BUFFER
+    buf = configure_buffer(512)
+    try:
+        sess = ResilienceSession(cyclic_assignment(96, 4, 2))
+        for _ in range(2):
+            sess.kmedian(pts, 3, alive, local_iters=2, coord_iters=2)
+        rows = [r for r in buf.rows()
+                if r["name"].startswith(("session.", "kmedian."))]
+    finally:
+        trace_mod._BUFFER = prev
+    rows.sort(key=lambda r: r["span"])  # ids are handed out as spans start
+    names = [r["name"] for r in rows]
+    solve = ["session.pack", "session.fingerprint", "kmedian.upload", "kmedian.local",
+             "kmedian.coordinator", "kmedian.cost"]
+    assert names == ["session.recovery_solve"] + solve + solve
+    by_id = {r["span"]: r for r in rows}
+    for r in rows:
+        if r["name"] == "session.fingerprint":
+            assert by_id[r["parent"]]["name"] == "session.pack"
+        else:
+            assert r["parent"] is None
+    packs = [r for r in rows if r["name"] == "session.pack"]
+    assert [p["attrs"]["hit"] for p in packs] == [False, True]
+    assert packs[0]["attrs"]["rows"] == 4 * 48
+    assert packs[0]["attrs"]["bytes"] == 4 * 48 * 3 * 4 + 4 * 48 * 4
+    fp = next(r for r in rows if r["name"] == "session.fingerprint")
+    assert fp["attrs"]["bytes"] == pts.nbytes
+
+
 def test_coverage_validation_computed_once_per_pattern():
     """Satellite fix: the per-call shard-coverage re-validation in the
     algorithm prelude is hoisted into the session and cached per pattern —

@@ -333,3 +333,92 @@ def test_generation_bump_auto_warms_and_env_opts_out(monkeypatch):
     sess.ingest(rng.normal(size=(80, D)).astype(np.float32))
     sess.solve()
     assert fe.warmups == before + 1
+
+
+# ------------------------------------------------------- queue-wait stamps
+
+
+def test_dispatch_stamps_tickets_and_counts_queue_wait_once():
+    from repro.obs import default_registry
+
+    fe, clk = make_frontend(make_session())
+    reg = default_registry()
+    rng = np.random.default_rng(7)
+    clk.set(10.0)
+    t1 = fe.submit("a", rng.normal(size=(2, D)))
+    clk.advance(0.0005)
+    t2 = fe.submit("a", rng.normal(size=(3, D)))
+    clk.advance(WINDOW - 0.0005)
+    assert t1.dispatched_at is None and t1.completed_at is None
+    assert fe.flush() == 1
+    assert t1.dispatched_at == t2.dispatched_at == clk.now()
+    assert t1.completed_at == t2.completed_at == clk.now()
+    # (10.002 − 10.0) + (10.002 − 10.0005): one increment for the dispatch.
+    assert reg.value("serve_queue_wait_seconds", fe._obs_labels) == pytest.approx(
+        2 * WINDOW - 0.0005)
+    assert reg.value("serve_dispatched_tickets", fe._obs_labels) == 2
+    # A cache hit completes at submit and is never dispatched.
+    clk.advance(1.0)
+    hit = fe.submit("a", t1.queries)
+    assert hit.from_cache
+    assert hit.completed_at == clk.now() and hit.dispatched_at is None
+    assert reg.value("serve_dispatched_tickets", fe._obs_labels) == 2
+
+
+class _TickingClock(VirtualClock):
+    """Moves on by ``tick`` after every reading, so that each stamp the
+    frontend takes (submit, dispatch, completion) is a distinct time."""
+
+    def __init__(self, tick):
+        super().__init__()
+        self.tick = tick
+
+    def now(self):
+        t = super().now()
+        self.advance(self.tick)
+        return t
+
+
+def test_latency_and_queue_wait_are_read_from_the_ticket_stamps():
+    from repro.obs import default_registry
+
+    clk = _TickingClock(0.0001)
+    fe = ServingFrontend(window=WINDOW, max_batch=64, cache_size=128, clock=clk)
+    fe.add_tenant("a", make_session())
+    rng = np.random.default_rng(11)
+    tickets = [fe.submit("a", rng.normal(size=(2, D))) for _ in range(3)]
+    clk.advance(WINDOW)
+    assert fe.flush() == 1
+    for t in tickets:
+        assert t.submitted_at < t.dispatched_at < t.completed_at
+    snap = fe._lat_hist("a").snapshot()
+    assert snap.samples == pytest.approx(
+        sorted((t.completed_at - t.submitted_at) * 1e6 for t in tickets))
+    assert default_registry().value(
+        "serve_queue_wait_seconds", fe._obs_labels
+    ) == pytest.approx(sum(t.dispatched_at - t.submitted_at for t in tickets))
+
+
+def test_dispatch_span_holds_fetch_and_is_followed_by_complete():
+    from repro.obs import configure_buffer
+    from repro.obs import trace as trace_mod
+
+    trace_mod.flush()  # pause spans queued by earlier tests go to the old ring
+    prev = trace_mod._BUFFER
+    buf = configure_buffer(256)
+    try:
+        fe, clk = make_frontend(make_session())
+        fe.submit("a", np.ones((4, D), np.float32))
+        clk.advance(WINDOW)
+        assert fe.flush() == 1
+        rows = [r for r in buf.rows() if r["name"].startswith("serve.")]
+    finally:
+        trace_mod._BUFFER = prev
+    # The ring takes a span as it ends: the fetch inside the dispatch first,
+    # then the dispatch, then the completion that follows it.
+    assert [r["name"] for r in rows] == ["serve.fetch", "serve.dispatch", "serve.complete"]
+    fetch, dispatch, complete = rows
+    assert fetch["parent"] == dispatch["span"]
+    assert complete["parent"] == dispatch["parent"]
+    assert fetch["attrs"] == {"rows": 4, "bucket": dispatch["attrs"]["bucket"]}
+    assert complete["attrs"] == {"tickets": 1}
