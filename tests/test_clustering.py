@@ -17,6 +17,7 @@ from repro.core import (
     lloyd,
     lloyd_subspace,
     pca_cost,
+    plusplus_init,
     relaxed_coreset_rank,
     resilient_kmedian,
     resilient_pca,
@@ -52,6 +53,66 @@ def test_lloyd_weighted_ignores_zero_weight_padding():
     )
     # Padded garbage points must not attract centers.
     assert np.abs(np.asarray(res_pad.centers)).max() < 100.0
+
+
+def _full_recompute_plusplus(key, x, k, w, median):
+    """Reference seeding: every step re-assigns every row against
+    all k slots (unchosen slots hold the first point) with ``assign_min``."""
+    from repro.kernels.pairwise_dist.ops import assign_min
+
+    d = x.shape[1]
+
+    def logits_of(score):
+        return jnp.where(w > 0, jnp.log(jnp.maximum(w * score, 1e-12)), -jnp.inf)
+
+    key0, key = jax.random.split(key)
+    first = jax.random.categorical(key0, logits_of(jnp.ones_like(w)))
+    centers0 = jnp.broadcast_to(x[first][None, :], (k, d))
+
+    def body(i, carry):
+        centers, key = carry
+        key, sub = jax.random.split(key)
+        _, d2 = assign_min(x, centers, impl="xla_ref")
+        score = d2 if not median else jnp.sqrt(jnp.maximum(d2, 0.0))
+        nxt = jax.random.categorical(sub, logits_of(score))
+        return centers.at[i].set(x[nxt]), key
+
+    return jax.lax.fori_loop(1, k, body, (centers0, key))[0]
+
+
+@pytest.mark.parametrize("median", [True, False], ids=["median", "means"])
+@pytest.mark.parametrize("padded", [False, True], ids=["dense", "zero_weight_pad"])
+def test_incremental_seeding_draws_the_full_recompute_rows(median, padded):
+    k = 8
+    pts, _, _ = gaussian_mixture(
+        600, k, 6, spread=0.01, box=10.0, rng=np.random.default_rng(11)
+    )
+    w = np.ones(len(pts), np.float32)
+    if padded:  # far-off rows that a nonzero weight would make certain picks
+        pts = np.concatenate([pts, np.full((40, 6), 1e3, np.float32)])
+        w = np.concatenate([w, np.zeros(40, np.float32)])
+    x, wj = jnp.asarray(pts, jnp.float32), jnp.asarray(w)
+    for seed in range(4):
+        key = jax.random.PRNGKey(seed)
+        new = np.asarray(plusplus_init(key, x, k, weights=wj, median=median))
+        old = np.asarray(_full_recompute_plusplus(key, x, k, wj, median))
+        np.testing.assert_array_equal(new, old)
+        if padded:
+            assert np.abs(new).max() < 100.0
+
+
+def test_seeding_makes_no_assign_min_call(monkeypatch):
+    from repro.kernels.pairwise_dist import ops as pd
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("seeding called assign_min")
+
+    monkeypatch.setattr(pd, "assign_min", refuse)
+    # A shape no other test seeds at, so no cached trace can hide a call.
+    x = jnp.asarray(np.random.default_rng(5).normal(size=(37, 3)), jnp.float32)
+    centers = plusplus_init(jax.random.PRNGKey(9), x, 5, median=True)
+    assert centers.shape == (5, 3)
+    assert np.isin(np.asarray(centers), np.asarray(x)).all(axis=None)
 
 
 def test_kmedian_cost_uses_unsquared_distance():
