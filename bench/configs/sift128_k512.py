@@ -113,14 +113,28 @@ def min_delta_recovery(alive: np.ndarray, s: int, ell: int) -> tuple:
     return b_full, float(a[covered].max() - 1.0), covered
 
 
+def recovery_weights(alive: np.ndarray, *, s: int, ell: int,
+                     ignore_stragglers: bool = False) -> np.ndarray:
+    """Each worker's recovery weight b (s,): the least-δ weights, or with
+    ``ignore_stragglers`` weight 1 on every alive worker (the paper's Fig.
+    1(b) baseline, which breaks the recovery guarantee)."""
+    if ignore_stragglers:
+        return np.asarray(alive, bool).astype(np.float64)
+    return min_delta_recovery(alive, s, ell)[0]
+
+
 def coverage_gap(summary_weights: np.ndarray, alive: np.ndarray, *, s: int, ell: int,
                  k: int, rows_per_node: int) -> float:
     """How far the weights a solve gave the coordinator fall outside the
     recovery band.  Worker i's center weights sum to b_i times its row
-    count, so b_i is read back from them; every covered shard's total
-    Σ_{i∋j} b_i must lie in [1, 1+δ*]."""
+    count, so b_i is read back from them."""
     W = np.asarray(summary_weights, np.float64).reshape(s, k).sum(axis=1)
-    b = W / rows_per_node
+    return band_gap(W / rows_per_node, alive, s=s, ell=ell)
+
+
+def band_gap(b: np.ndarray, alive: np.ndarray, *, s: int, ell: int) -> float:
+    """How far recovery weights b fall outside the recovery band: every
+    covered shard's total Σ_{i∋j} b_i must lie in [1, 1+δ*]."""
     _, delta, covered = min_delta_recovery(alive, s, ell)
     if not covered.any():
         return 0.0
@@ -245,10 +259,7 @@ def ref_kmedian(points: np.ndarray, alive: np.ndarray, *, k: int, s: int, ell: i
     """
     alive = np.asarray(alive, bool)
     d = points.shape[1]
-    if ignore_stragglers:
-        b = alive.astype(np.float64)
-    else:
-        b, _, _ = min_delta_recovery(alive, s, ell)
+    b = recovery_weights(alive, s=s, ell=ell, ignore_stragglers=ignore_stragglers)
     rows = cyclic_rows(len(points), s, ell)
     keys = jax.random.split(jax.random.PRNGKey(seed), s)
     y = np.zeros((s, k, d), np.float32)

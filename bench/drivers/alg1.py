@@ -133,6 +133,7 @@ STAND_INS = {
     "recovery": dict(ignore_stragglers=True),
 }
 NUMBERS = ("weight_gap", "summary_gap", "size_gap", "recost_gap")
+STAND_IN_MASKS = 64  # masks of the schedule a stand-in's recovery weights are read on
 
 
 def compared_numbers(st: State, program_solves: list, *, stand_in: str = "") -> dict:
@@ -154,7 +155,9 @@ def compared_numbers(st: State, program_solves: list, *, stand_in: str = "") -> 
       sum over all points of the distance to the nearest returned center.
 
     ``stand_in`` names an entry of ``STAND_INS``: the reference so set
-    stands in the program's place on the sampled solves."""
+    stands in the program's place, with its recovery weights on the
+    schedule's masks (``weight_gap``) and its whole pipeline on the sampled
+    solves."""
     cfg, ref = st.cfg, st.cell.config_module
     s, ell, k, n = cfg["nodes"], cfg["ell"], cfg["k"], cfg["n"]
     kw = dict(k=k, s=s, ell=ell, local_iters=cfg["local_iters"],
@@ -167,19 +170,31 @@ def compared_numbers(st: State, program_solves: list, *, stand_in: str = "") -> 
                            replace=False))
     checked = [done[i] for i in pick]
     if stand_in:
+        opts = STAND_INS[stand_in]
+        ignore = opts.get("ignore_stragglers", False)
+        # Over the schedule's first masks, and at least as far as the
+        # window went, so that the reading does not hang on how many solves
+        # fit the window.
+        upto = max(STAND_IN_MASKS, done[-1].index + 1)
+        weight_gap = max(
+            ref.band_gap(ref.recovery_weights(alive, s=s, ell=ell, ignore_stragglers=ignore),
+                         alive, s=s, ell=ell)
+            for alive in st.masks[:upto] if alive.any()
+        )
         replaced = []
         for sv in checked:
-            out = ref.ref_kmedian(st.datasets[sv.dataset], sv.alive, seed=sv.seed,
-                                  **STAND_INS[stand_in], **kw)
+            out = ref.ref_kmedian(st.datasets[sv.dataset], sv.alive, seed=sv.seed, **opts, **kw)
             replaced.append(dataclasses.replace(
                 sv, centers=out["centers"], cost=out["cost"],
                 summary_points=out["summary_points"], summary_weights=out["summary_weights"]))
-        done = checked = replaced
-    rows = ell * n // s
-    weight_gap = max(
-        ref.coverage_gap(sv.summary_weights, sv.alive, s=s, ell=ell, k=k, rows_per_node=rows)
-        for sv in done
-    )
+        checked = replaced
+    else:
+        rows = ell * n // s
+        weight_gap = max(
+            ref.coverage_gap(sv.summary_weights, sv.alive, s=s, ell=ell, k=k,
+                             rows_per_node=rows)
+            for sv in done
+        )
     summary_gap = size_gap = recost_gap = 0.0
     for sv in checked:
         points = st.datasets[sv.dataset]

@@ -9,6 +9,10 @@ of the window from the seed: arrival time, tenant, and 1–64 query rows
 when it falls due and flushes whatever batches have closed.  A request's
 latency runs from when the schedule said it was due to when its answer is
 on the host, so a stall that delays later submissions is charged to them.
+The window reports the median of every request's latency; its 95th
+percentile is noted, and read per layer over the requests due in a traced
+run's profiled stretch: over a whole window a single stall of the host of a
+second or more sets it.
 
 Traffic parameters (``bench/traffic/<mix>.json``): ``rate`` (requests per
 second, fixed below the measured knee), ``zipf``, ``rows`` (least and most
@@ -27,7 +31,7 @@ import numpy as np
 import gen
 import harness
 
-PCT = 95.0
+TAIL = 95.0
 
 
 class State:
@@ -121,11 +125,13 @@ def window(st: State, seconds: float, tracer: harness.Tracer) -> harness.WindowR
     # the per-layer counters are read where it stops.
     trace_until = float(st.tr["trace_seconds"]) if tracer.enabled else np.inf
     layer = None
+    held = _Held()
     tracer.begin()
     t0 = time.perf_counter()
     i = 0
     while True:
         now = time.perf_counter() - t0
+        held.step(now)
         if layer is None and now >= trace_until:
             layer = _layer_counters(fe, d0, s0)
             tracer.end()
@@ -138,8 +144,10 @@ def window(st: State, seconds: float, tracer: harness.Tracer) -> harness.WindowR
                 print(f"request {i} refused: {e!r}", file=sys.stderr)
             late[i] = now - due[i]
             i += 1
+        held.mark("flush", time.perf_counter() - t0)
         fe.flush()
         stamp = time.perf_counter() - t0
+        held.mark("sleep", stamp)
         still = []
         for j, t in outstanding:
             if not t.done:
@@ -160,20 +168,98 @@ def window(st: State, seconds: float, tracer: harness.Tracer) -> harness.WindowR
         if pause > 0:
             time.sleep(min(pause, 0.05))
     tracer.end()
+    held.close()
     lat_ms = (done_at - due) * 1e3
-    p95 = float(np.percentile(lat_ms, PCT)) if n else float("inf")
+    p50 = float(np.median(lat_ms)) if n else float("inf")
+    p95 = float(np.percentile(lat_ms, TAIL)) if n else float("inf")
     beyond = int(np.sum(lat_ms > p95))
     if layer is None:
         layer = _layer_counters(fe, d0, s0)
     notes = [
         f"served requests {n} rows {fe.served - s0} dispatches {fe.dispatches - d0} failed {failed}",
         f"late generator p50_ms {float(np.median(late)) * 1e3!r} max_ms {float(late.max()) * 1e3 if n else 0.0!r}",
-        f"tail p50_ms {float(np.median(lat_ms))!r} p95_ms {p95!r} beyond_p95 {beyond}",
+        f"tail p50_ms {p50!r} p95_ms {p95!r} beyond_p95 {beyond}",
+        *held.notes(),
     ]
     return harness.WindowResult(
-        attempted=n, failed=failed, metrics={"query_p95_ms": p95},
-        counters={"requests": n, "latency_ms": lat_ms, **layer}, notes=notes,
+        attempted=n, failed=failed, metrics={"query_p50_ms": p50},
+        counters={"requests": n, "latency_ms": lat_ms,
+                  "stretch_latency_ms": lat_ms[due < trace_until], **layer},
+        notes=notes,
     )
+
+
+class _Held:
+    """Where the submitting loop was held up: each pass of the loop that took
+    ``HELD_S`` or more, with the phase it stood in (``submit``, ``flush`` or
+    ``sleep``, a sleep asking 50 ms at most), its wall and process CPU
+    seconds; and the compiles and collections that ran in the window.  A
+    hold with next to no CPU in a sleep is the host standing still, not the
+    program."""
+
+    HELD_S = 0.1
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.rows = []
+        self.compiles = []
+        self.gc_s = []
+        self._gc_t = None
+        self._open = True
+        self._prev = None
+        self._marks = {}
+        self._cpu = time.process_time()
+
+        def on_compile(event, duration, **kwargs):
+            if self._open and event == "/jax/core/compile/backend_compile_duration":
+                self.compiles.append((str(kwargs.get("fun_name", "")), duration))
+
+        def on_gc(phase, info):
+            if not self._open:
+                return
+            if phase == "start":
+                self._gc_t = time.perf_counter()
+            elif self._gc_t is not None:
+                self.gc_s.append(time.perf_counter() - self._gc_t)
+
+        jax.monitoring.register_event_duration_secs_listener(on_compile)
+        gc.callbacks.append(on_gc)
+        self._on_gc = on_gc
+
+    def mark(self, phase: str, at: float) -> None:
+        self._marks[phase] = at
+
+    def step(self, now: float) -> None:
+        """The loop is back at its top at ``now``: note the pass just ended
+        if it held the loop."""
+        cpu = time.process_time()
+        prev = self._prev
+        if prev is not None and now - prev >= self.HELD_S:
+            f = self._marks.get("flush", prev)
+            s = self._marks.get("sleep", f)
+            walls = {"submit": f - prev, "flush": s - f, "sleep": now - s}
+            phase = max(walls, key=walls.get)
+            self.rows.append((prev, now - prev, phase, walls[phase], cpu - self._cpu))
+        self._prev, self._cpu = now, cpu
+        self._marks.clear()
+
+    def close(self) -> None:
+        self._open = False
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def notes(self) -> list:
+        worst = sorted(self.rows, key=lambda r: -r[1])[:6]
+        return [
+            f"held passes {len(self.rows)} total_s {sum(r[1] for r in self.rows)!r} longest "
+            + " ".join(f"[at_s {a:.3f} wall_s {w:.3f} {p} {pw:.3f} cpu_s {c:.3f}]"
+                       for a, w, p, pw, c in worst),
+            f"window compiles {len(self.compiles)} s {sum(d for _, d in self.compiles)!r} "
+            + " ".join(sorted({n for n, _ in self.compiles})[:8]),
+            f"window collections {len(self.gc_s)} s {sum(self.gc_s)!r} "
+            f"max_ms {1e3 * max(self.gc_s, default=0.0)!r}",
+        ]
 
 
 def _layer_counters(fe, d0: int, s0: int) -> dict:

@@ -26,16 +26,18 @@ TINY = {
     },
     # At this size bfloat16 rounds the small leaves' gradients more coarsely
     # than at the published widths, so the comparison keeps limits of its
-    # own, set the same way from this size's readings (CPU, seeds 1 to 3):
-    # sound loss/grad/update gaps up to 7.6e-5/1.2e-3/6.5e-4, the float8
-    # control's from 5.8e-4/6.4e-3/1.0e-3, half of the batch left out
-    # from 2.7e-3/4.6e-2/6.1e-3, an unchanged state 1 on the update.
+    # own, set the same way from this size's readings (CPU, seeds 1 to 6):
+    # sound loss/grad/mean-grad/update gaps up to 8.7e-5/1.3e-3/3.1e-4/
+    # 6.5e-4, the float8 control's from 2.8e-4/5.4e-3/1.5e-3/1.0e-3, half of
+    # the batch left out from 2.7e-3/2.8e-2/9.7e-3/6.1e-3, an unchanged
+    # state 1 on the update.
     "train.qwen3_1_7b.fr4": {
         "files": ("qwen3_1_7b", "fr4"),
         "config": {"hidden_size": 256, "intermediate_size": 512, "num_attention_heads": 4,
                    "num_key_value_heads": 2, "head_dim": 64, "vocab_size": 2048,
                    "training": {"seq_len": 64}},
-        "traffic": {"limits": {"loss_gap": 2e-4, "grad_gap": 3e-3, "update_gap": 3e-3}},
+        "traffic": {"limits": {"loss_gap": 2e-4, "grad_gap": 3e-3, "grad_mean_gap": 7e-4,
+                               "update_gap": 3e-3}},
     },
     "serve.sift128_k512.zipf4": {
         "files": ("sift128_k512", "zipf4"),

@@ -3,12 +3,13 @@ and the metrics that use it): on a fresh registry and span ring filled by
 hand, where what they read is there and where it is not (as on a program
 that lacks it), and on what a tiny window of each cell leaves."""
 
+import numpy as np
 import pytest
 
 ALG1 = "alg1.sift128_k512.deadline"
 SERVE = "serve.sift128_k512.zipf4"
 ALG1_METRICS = ("pack_ms.alg1", "recovery_ms.alg1", "host_pause_ms.alg1")
-SERVE_METRICS = ("fetch_ms.serve",)
+SERVE_METRICS = ("fetch_ms.serve", "query_p95_ms.serve")
 
 
 @pytest.fixture
@@ -96,6 +97,10 @@ def test_serve_readers(fresh_program):
     for i in range(4):
         tm.record_span("serve.fetch", 0.0, 0.0005 * (i + 1))
     assert _read("fetch_ms.serve", ctx) == pytest.approx(1.25)
+    ctx["counters"]["stretch_latency_ms"] = np.arange(1.0, 201.0)
+    assert _read("query_p95_ms.serve", ctx) == pytest.approx(190.05)
+    ctx["counters"]["stretch_latency_ms"] = np.zeros(0)
+    assert _read("query_p95_ms.serve", ctx) is None
 
 
 @pytest.mark.parametrize("cell,metrics", [(ALG1, ALG1_METRICS), (SERVE, SERVE_METRICS)])
@@ -115,3 +120,4 @@ def test_tiny_window_reads_every_new_metric(cell, metrics):
         assert _read("pack_ms.alg1", ctx) > 0.0
     else:
         assert _read("fetch_ms.serve", ctx) > 0.0
+        assert _read("query_p95_ms.serve", ctx) > 0.0

@@ -10,7 +10,8 @@ def test_sound_run_line(run_tiny):
     assert list(line) == KEYS
     assert line["correct"] is True
     assert line["attempted"] > 0
-    assert set(line["metrics"]) == {"query_p95_ms", "setup_s"}
+    assert set(line["metrics"]) == {"query_p50_ms", "setup_s"}
+    assert line["metrics"]["query_p50_ms"]["value"] > 0.0
 
 
 def test_control_is_not_correct(readings_tiny):

@@ -21,9 +21,9 @@ def test_sound_run_line(run_tiny):
     line = run_tiny(CELL)
     assert list(line) == KEYS
     assert line["correct"] is True
-    # The cell waits outside BENCHMARK.json for its chip runs at six
-    # layers, so a run reports only the metric every cell reports.
-    assert set(line["metrics"]) == {"setup_s"}
+    assert line["attempted"] > 0 and line["failed"] == 0
+    assert set(line["metrics"]) == {"train_tokens_per_s", "setup_s"}
+    assert line["metrics"]["train_tokens_per_s"]["value"] > 0
 
 
 def test_control_and_half_batch_are_not_correct(readings_tiny):
@@ -31,6 +31,20 @@ def test_control_and_half_batch_are_not_correct(readings_tiny):
     assert all(got["sound"][k] <= v for k, v in limits.items()), got
     assert any(got["control"][k] > v for k, v in limits.items()), got
     assert any(got["half_batch"][k] > v for k, v in limits.items())
+
+
+def test_change_norm_sums_in_float64():
+    """A leaf's change is as long as hundreds of millions of elements; its
+    norm is read with the squares summed in float64, where a float32 sum
+    reads low."""
+    import numpy as np
+    import harness
+
+    ref = harness.config_module("qwen3_1_7b")
+    a = np.random.default_rng(0).standard_normal(1 << 24, dtype=np.float32)
+    b = np.zeros_like(a)
+    exact = float(np.sqrt(np.sum(np.square(a.astype(np.float64)))))
+    assert abs(ref.host_diff_norm(a, b) / exact - 1) < 1e-9
 
 
 def test_state_returned_unchanged(run_tiny, monkeypatch, fresh_step_cache):
