@@ -4,7 +4,7 @@ architectures with repro.models.registry."""
 from . import (  # noqa: F401
     deepseek_moe_16b,
     internvl2_1b,
-    moonshot_v1_16b_a3b,
+    moonlight_16b_a3b,
     musicgen_large,
     qwen2_5_3b,
     qwen3_1_7b,
@@ -19,7 +19,7 @@ ARCHS = [
     "qwen3-8b",
     "qwen2.5-3b",
     "qwen3-1.7b",
-    "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b",
     "deepseek-moe-16b",
     "xlstm-1.3b",
     "internvl2-1b",

@@ -3,8 +3,9 @@ vocab=102400, MoE 64e top-6 — 2 shared + 64 routed top-6, fine-grained.
 [arXiv:2401.06066; hf]
 
 DeepSeekMoE-16B uses softmax router scores without top-k renormalization.
-Deviation noted in DESIGN.md §8: layer 0 of the real checkpoint is dense; we
-keep all layers MoE for a homogeneous scan unit.
+Deviations noted in DESIGN.md §8: layer 0 of the real checkpoint is dense; we
+keep all layers MoE for a homogeneous scan unit.  Its expert-level balance
+loss (α = 0.001) is taken per sequence here, as the repo's one balance term.
 """
 
 import dataclasses
@@ -31,7 +32,7 @@ def config() -> ModelConfig:
         mlp_act="silu_glu",
         moe=MoEConfig(
             num_experts=64, top_k=6, d_expert=1408, num_shared=2,
-            capacity_factor=1.25, router_score="softmax", renorm_topk=False,
+            router_score="softmax", renorm_topk=False, balance_weight=0.001,
         ),
     )
 

@@ -52,7 +52,7 @@ def _flash_kernel(
     def _body():
         q = q_ref[0].astype(jnp.float32) * scale  # (bq, dh)
         k = k_ref[0].astype(jnp.float32)  # (bk, dh)
-        v = v_ref[0].astype(jnp.float32)  # (bk, dh)
+        v = v_ref[0].astype(jnp.float32)  # (bk, dv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bq, bk)
@@ -85,12 +85,14 @@ def flash_attention_kernel_call(
     q, k, v, *, group: int, causal: bool, scale: float,
     bq: int = 512, bk: int = 512, interpret: bool,
 ):
-    """q: (BH, T, dh); k, v: (BKV, S, dh) with BH = BKV · group.
+    """q: (BH, T, dh); k: (BKV, S, dh); v: (BKV, S, dv) with BH = BKV · group
+    (dv may differ from dh, as in latent attention).
 
-    T % bq == 0 and S % bk == 0 (wrapper pads).  Returns (BH, T, dh).
+    T % bq == 0 and S % bk == 0 (wrapper pads).  Returns (BH, T, dv).
     """
     BH, T, dh = q.shape
     BKV, S, _ = k.shape
+    dv = v.shape[-1]
     assert BH == BKV * group, (BH, BKV, group)
     assert T % bq == 0 and S % bk == 0, (T, S, bq, bk)
     grid = (BH, T // bq, S // bk)
@@ -103,14 +105,14 @@ def flash_attention_kernel_call(
         in_specs=[
             pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, dh), lambda b, i, j, g=group: (b // g, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, i, j, g=group: (b // g, j, 0)),
+            pl.BlockSpec((1, bk, dv), lambda b, i, j, g=group: (b // g, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, T, dh), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((BH, T, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, dh), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)

@@ -43,9 +43,10 @@ def _pick_chunks(T: int, S: int, window) -> tuple[int, int]:
 
 
 def chunked_attention(q, k, v, *, causal=True, window=None, scale=None):
-    """Flash attention in pure jnp.  q: (B,T,H,dh); k,v: (B,S,KV,dh)."""
+    """Flash attention in pure jnp.  q, k: (B,T|S,H|KV,dh); v: (B,S,KV,dv)."""
     B, T, H, dh = q.shape
     S, KV = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     g = H // KV
     scale = (dh ** -0.5) if scale is None else scale
     cq, ck = _pick_chunks(T, S, window)
@@ -86,12 +87,12 @@ def chunked_attention(q, k, v, *, causal=True, window=None, scale=None):
         init = (
             jnp.full((B, KV, g, cq), -jnp.inf, jnp.float32),
             jnp.zeros((B, KV, g, cq), jnp.float32),
-            jnp.zeros((B, KV, g, cq, dh), jnp.float32),
+            jnp.zeros((B, KV, g, cq, dv), jnp.float32),
         )
         (m, l, acc), _ = jax.lax.scan(body, init, j0 + jnp.arange(n_blocks))
         safe = jnp.where(l > 0.0, l, 1.0)
-        o = (acc / safe[..., None]).transpose(0, 3, 1, 2, 4)  # (B,cq,KV,g,dh)
-        outs.append(o.reshape(B, cq, H, dh))
+        o = (acc / safe[..., None]).transpose(0, 3, 1, 2, 4)  # (B,cq,KV,g,dv)
+        outs.append(o.reshape(B, cq, H, dv))
     return jnp.concatenate(outs, axis=1).astype(q.dtype)
 
 
@@ -111,9 +112,10 @@ def _pallas_forward(q, k, v, causal, scale, interpret):
     recomputed in XLA — which is what lets a train step use the kernel."""
     B, T, H, dh = q.shape
     S, KV = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     g = H // KV
     # Shared VMEM tile model seeds the caps; shrink to exact divisors.
-    cfg = dispatch.pick_blocks(T, S, dh, bn_cap=512, bk_cap=512)
+    cfg = dispatch.pick_blocks(T, S, max(dh, dv), bn_cap=512, bk_cap=512)
     bq = min(cfg.bn, T)
     while T % bq:
         bq //= 2
@@ -122,12 +124,12 @@ def _pallas_forward(q, k, v, causal, scale, interpret):
         bk //= 2
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, T, dh)
     kf = k.transpose(0, 2, 1, 3).reshape(B * KV, S, dh)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * KV, S, dh)
+    vf = v.transpose(0, 2, 1, 3).reshape(B * KV, S, dv)
     out = _kernel.flash_attention_kernel_call(
         qf, kf, vf, group=g, causal=causal, scale=scale,
         bq=bq, bk=bk, interpret=interpret,
     )
-    return out.reshape(B, H, T, dh).transpose(0, 2, 1, 3)
+    return out.reshape(B, H, T, dv).transpose(0, 2, 1, 3)
 
 
 def _pallas_forward_fwd(q, k, v, causal, scale, interpret):
@@ -231,7 +233,8 @@ def _flash_attention_jit_dynscale(q, k, v, scale, *, causal, window, impl):
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None, impl="auto"):
-    """Dispatching attention.  Shapes: q (B,T,H,dh); k,v (B,S,KV,dh).
+    """Dispatching attention.  Shapes: q (B,T,H,dh); k (B,S,KV,dh); v
+    (B,S,KV,dv), where dv may differ from dh (latent attention).
 
     Resolution runs eagerly per call (env toggles honored); the compiled
     path is keyed on the resolved canonical impl name.
@@ -266,6 +269,7 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, window=None, scale=None):
     """
     B, _, H, dh = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
+    dv = v_cache.shape[-1]
     g = H // KV
     scale = (dh ** -0.5) if scale is None else scale
     # Read the cache in ITS OWN dtype and accumulate in f32 via the MXU
@@ -286,4 +290,4 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, window=None, scale=None):
         "bkgs,bskd->bkgd", p.astype(v_cache.dtype), v_cache,
         preferred_element_type=jnp.float32,
     )
-    return out.reshape(B, 1, H, dh).astype(q.dtype)
+    return out.reshape(B, 1, H, dv).astype(q.dtype)

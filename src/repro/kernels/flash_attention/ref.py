@@ -10,8 +10,8 @@ __all__ = ["attention_ref"]
 def attention_ref(q, k, v, *, causal: bool = True, scale=None):
     """Naive full-materialization attention oracle.
 
-    q: (B, T, H, dh); k, v: (B, S, KV, dh) with H % KV == 0 (GQA).
-    Returns (B, T, H, dh) in q.dtype; softmax in f32.
+    q: (B, T, H, dh); k: (B, S, KV, dh); v: (B, S, KV, dv) with H % KV == 0
+    (GQA).  Returns (B, T, H, dv) in q.dtype; softmax in f32.
     """
     B, T, H, dh = q.shape
     S, KV = k.shape[1], k.shape[2]

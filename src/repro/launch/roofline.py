@@ -139,13 +139,21 @@ def _active_params(cfg) -> tuple[int, int]:
     gated = 3 * d * cfg.d_ff if cfg.mlp_act != "gelu" else 2 * d * cfg.d_ff
     per_type["attn_mlp"] = attn + gated
     per_type["lattn_mlp"] = attn + 3 * d * cfg.d_ff
+    if cfg.kv_lora_rank:
+        dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+                         cfg.kv_lora_rank)
+        mla = d * H * (dn + dr) + d * (r + dr) + r * H * (dn + dv) + H * dv * d
+        per_type["mla_mlp"] = mla + gated
     if cfg.moe:
         m = cfg.moe
-        routed_total = m.num_experts * 3 * d * m.d_expert
-        routed_active = m.top_k * 3 * d * m.d_expert
+        routed_total = m.held * 3 * d * m.d_expert
+        routed_active = m.top_k * 3 * d * m.d_expert * m.held // m.num_experts
         shared = 3 * d * (m.d_expert * m.num_shared)
         per_type["attn_moe"] = attn + routed_total + shared + d * m.num_experts
         per_type["attn_moe_active"] = attn + routed_active + shared + d * m.num_experts
+        if cfg.kv_lora_rank:
+            per_type["mla_moe"] = per_type["attn_moe"] - attn + mla
+            per_type["mla_moe_active"] = per_type["attn_moe_active"] - attn + mla
     di = int(cfg.mlstm_proj_factor * d)
     per_type["mlstm"] = d * 2 * di + 3 * di * di + di * d + 2 * di * cfg.conv_width
     dff_s = int(cfg.slstm_proj_factor * d)
@@ -156,9 +164,7 @@ def _active_params(cfg) -> tuple[int, int]:
     active = head  # lm head is a matmul per token; embedding lookups are gathers
     for bt in cfg.block_types:
         total += per_type[bt]
-        active += per_type[
-            "attn_moe_active" if (bt == "attn_moe" and cfg.moe) else bt
-        ]
+        active += per_type[f"{bt}_active" if bt.endswith("_moe") else bt]
     return int(total), int(active)
 
 
@@ -168,7 +174,7 @@ def model_flops(cfg, shape) -> dict:
     B, T = shape.global_batch, shape.seq_len
     total, active = _active_params(cfg)
     H, dh = cfg.n_heads, cfg.head_dim
-    n_attn = sum(1 for b in cfg.block_types if b in ("attn_mlp", "attn_moe"))
+    n_attn = sum(1 for b in cfg.block_types if b in ("attn_mlp", "attn_moe", "mla_mlp", "mla_moe"))
     n_lattn = sum(1 for b in cfg.block_types if b == "lattn_mlp")
     n_mlstm = sum(1 for b in cfg.block_types if b == "mlstm")
     W = cfg.window or T

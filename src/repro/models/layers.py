@@ -12,6 +12,7 @@ __all__ = [
     "rmsnorm",
     "rope_freqs",
     "apply_rope",
+    "apply_rope_interleaved",
     "mlp_init",
     "mlp_apply",
     "causal_conv1d_init",
@@ -53,6 +54,22 @@ def apply_rope(x, positions, theta: float):
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def apply_rope_interleaved(x, positions, theta: float):
+    """RoPE over adjacent pairs (x[2i], x[2i+1]), the pairing of DeepSeek's
+    published MLA weights.  x: (B, T, H, dh); positions: (T,) or (B, T)."""
+    dh = x.shape[-1]
+    pos = positions.astype(jnp.float32)
+    if pos.ndim == 1:
+        pos = pos[None, :]
+    ang = pos[..., None] * rope_freqs(dh, theta)[None, None, :]  # (B?, T, dh/2)
+    cos = jnp.cos(ang)[:, :, None, :]
+    sin = jnp.sin(ang)[:, :, None, :]
+    xp = x.astype(jnp.float32).reshape(x.shape[:-1] + (dh // 2, 2))
+    x1, x2 = xp[..., 0], xp[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 def mlp_init(key, d: int, d_ff: int, *, gated: bool, dtype=jnp.float32):

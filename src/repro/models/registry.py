@@ -2,13 +2,16 @@
 
 Every assigned architecture is a :class:`ModelConfig`; block heterogeneity
 (hybrids like RecurrentGemma and xLSTM) is expressed as a repeating
-``scan_unit`` of block types plus an optional ``tail`` — the layer stack is
-``scan_unit × scan_repeats  +  tail`` and is executed as a ``lax.scan`` over
-the repeats (compile time O(1) in depth).
+``scan_unit`` of block types between an optional ``lead`` and ``tail`` —
+the layer stack is ``lead  +  scan_unit × scan_repeats  +  tail`` and the
+unit is executed as a ``lax.scan`` over the repeats (compile time O(1) in
+depth).
 
 Block types:
   attn_mlp   — GQA attention + gated/plain MLP        (dense transformers)
   attn_moe   — GQA attention + routed MoE (+ shared)  (MoE transformers)
+  mla_mlp    — latent attention (MLA) + gated MLP     (DeepSeek-V2/V3 dense layers)
+  mla_moe    — latent attention (MLA) + routed MoE    (DeepSeek-V2/V3 expert layers)
   mlstm      — xLSTM matrix-memory block (chunkwise-parallel / recurrent)
   slstm      — xLSTM scalar-memory block (sequential scan)
   rglru_mlp  — RG-LRU recurrent block + MLP           (Griffin/RecurrentGemma)
@@ -25,14 +28,25 @@ __all__ = ["MoEConfig", "ModelConfig", "register", "get_config", "list_archs"]
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int = 64
+    num_experts: int = 64  # the router's width: experts a token is routed over
     top_k: int = 6
     d_expert: int = 1408
     num_shared: int = 2
-    capacity_factor: float = 1.25
-    router_aux_weight: float = 0.001
     router_score: str = "softmax"  # or "sigmoid" (DeepSeek-V3/Moonlight style)
     renorm_topk: bool = False
+    routed_scaling_factor: float = 1.0
+    # Experts whose weights this layer holds, numbered from 0 (expert
+    # parallelism: the chip's share of a layer).  None holds all of them.
+    experts_held: Optional[int] = None
+    # γ of DeepSeek-V3's auxiliary-loss-free balancing (noaux_tc): > 0 adds a
+    # per-expert selection bias, moved by γ·sign(mean load − load) each step.
+    bias_update_rate: float = 0.0
+    # α of the sequence-wise balance loss, summed over the expert layers.
+    balance_weight: float = 0.001
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None else self.experts_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,6 +62,7 @@ class ModelConfig:
     head_dim: int = 128
     scan_unit: tuple = ("attn_mlp",)
     tail: tuple = ()
+    lead: tuple = ()  # block types run once before the scanned unit
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 1e6
@@ -55,6 +70,12 @@ class ModelConfig:
     mlp_act: str = "silu_glu"  # silu_glu | gelu_glu | gelu
     moe: Optional[MoEConfig] = None
     window: Optional[int] = None  # sliding-window size for lattn blocks
+    # Latent attention (MLA, DeepSeek-V2 §2.1) for the mla_* blocks
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None  # compressed queries: not implemented
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # xLSTM specifics
     mlstm_proj_factor: float = 2.0
     slstm_proj_factor: float = 4.0 / 3.0
@@ -74,7 +95,7 @@ class ModelConfig:
 
     @property
     def scan_repeats(self) -> int:
-        body = self.n_layers - len(self.tail)
+        body = self.n_layers - len(self.lead) - len(self.tail)
         assert body % len(self.scan_unit) == 0, (
             f"{self.name}: {body} body layers not divisible by unit "
             f"{self.scan_unit}"
@@ -83,11 +104,16 @@ class ModelConfig:
 
     @property
     def block_types(self) -> tuple:
-        return self.scan_unit * self.scan_repeats + self.tail
+        return self.lead + self.scan_unit * self.scan_repeats + self.tail
 
     def validate(self) -> "ModelConfig":
         assert self.n_layers == len(self.block_types)
         assert self.n_heads % self.n_kv_heads == 0
+        if any(bt.startswith("mla_") for bt in self.block_types):
+            assert self.kv_lora_rank and self.qk_rope_head_dim and self.v_head_dim
+            assert self.q_lora_rank is None, "MLA with compressed queries is not implemented"
+        if self.moe is not None:
+            assert 0 < self.moe.held <= self.moe.num_experts
         return self
 
 

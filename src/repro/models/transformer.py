@@ -1,13 +1,16 @@
 """Generic decoder assembly for all ten assigned architectures.
 
-The layer stack is ``cfg.scan_unit × cfg.scan_repeats + cfg.tail``; the body
-runs as one ``lax.scan`` over the repeats with per-slot stacked parameters
-(compile time and HLO size O(1) in depth), optionally rematerialized.
+The layer stack is ``cfg.lead + cfg.scan_unit × cfg.scan_repeats +
+cfg.tail``; the body runs as one ``lax.scan`` over the repeats with per-slot
+stacked parameters (compile time and HLO size O(1) in depth), optionally
+rematerialized.
 
 Three entry points:
-  * :func:`forward_train`  — (B, T) tokens → logits (+ MoE aux loss)
-  * :func:`loss_fn`        — group-weighted CE; the recovery weights of the
-    paper's Lemma 3 enter *here* (see repro.train.resilient)
+  * :func:`forward_train`  — (B, T) tokens → logits (+ the expert layers'
+    routing statistics)
+  * :func:`loss_fn`        — group-weighted CE (+ the sequence-wise balance
+    loss); the recovery weights of the paper's Lemma 3 enter *here* (see
+    repro.train.resilient)
   * :func:`prefill` / :func:`decode_step` — serving paths with a pytree cache
     (KV for attention, recurrent state for mLSTM/sLSTM/RG-LRU)
 """
@@ -38,7 +41,11 @@ __all__ = [
     "prefill",
     "decode_step",
     "param_count",
+    "moe_bias_step",
 ]
+
+_ATTN_BLOCKS = ("attn_mlp", "attn_moe", "lattn_mlp", "mla_mlp", "mla_moe")
+_MOE_BLOCKS = ("attn_moe", "mla_moe")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,13 +81,14 @@ class ModelContext:
 
 def _block_init(key, bt: str, cfg: ModelConfig, dtype):
     ks = jax.random.split(key, 4)
-    if bt in ("attn_mlp", "attn_moe", "lattn_mlp"):
+    if bt in _ATTN_BLOCKS:
+        attn_init = A.mla_init if bt.startswith("mla_") else A.attn_init
         p = {
             "attn_norm": L.rmsnorm_init(cfg.d_model, dtype=dtype),
-            "attn": A.attn_init(ks[0], cfg, dtype=dtype),
+            "attn": attn_init(ks[0], cfg, dtype=dtype),
             "mlp_norm": L.rmsnorm_init(cfg.d_model, dtype=dtype),
         }
-        if bt == "attn_moe":
+        if bt in _MOE_BLOCKS:
             p["moe"] = M.moe_init(ks[1], cfg, dtype=dtype)
         else:
             p["mlp"] = L.mlp_init(
@@ -108,6 +116,11 @@ def init_params(key, cfg: ModelConfig):
     else:
         params["embed"] = jax.random.normal(keys[0], (V, d), dtype) * 0.02
 
+    if cfg.lead:
+        params["lead"] = {
+            f"lead{li}": _block_init(jax.random.fold_in(keys[4], li), bt, cfg, dtype)
+            for li, bt in enumerate(cfg.lead)
+        }
     unit = cfg.scan_unit
     reps = cfg.scan_repeats
     unit_params = {}
@@ -139,20 +152,24 @@ def param_count(params) -> int:
 
 
 def _block_apply(bt: str, p, x, cfg: ModelConfig, ctx: ModelContext, positions):
-    """Training/prefill forward for one block.  Returns (x, aux, cache)."""
+    """Training/prefill forward for one block.  Returns (x, moe, cache): moe
+    is the expert layer's routing statistics (None for other blocks)."""
     compute_dtype = jnp.dtype(cfg.compute_dtype)
-    aux = jnp.zeros((), jnp.float32)
+    moe = None
     cache = None
-    if bt in ("attn_mlp", "attn_moe", "lattn_mlp"):
+    if bt in _ATTN_BLOCKS:
         window = cfg.window if bt == "lattn_mlp" else None
         xn = L.rmsnorm(x, p["attn_norm"], eps=cfg.rms_eps)
-        a, kv = A.attn_apply(
-            p["attn"], xn, cfg, positions=positions, window=window, impl=ctx.attn_impl
-        )
+        if bt.startswith("mla_"):
+            a, kv = A.mla_apply(p["attn"], xn, cfg, positions=positions, impl=ctx.attn_impl)
+        else:
+            a, kv = A.attn_apply(
+                p["attn"], xn, cfg, positions=positions, window=window, impl=ctx.attn_impl
+            )
         x = x + a
         xn2 = L.rmsnorm(x, p["mlp_norm"], eps=cfg.rms_eps)
-        if bt == "attn_moe":
-            mo, aux = M.moe_apply(
+        if bt in _MOE_BLOCKS:
+            mo, moe = M.moe_apply(
                 p["moe"], xn2, cfg, mesh=ctx.mesh, batch_axes=ctx.batch_axes,
                 model_axis=ctx.model_axis, fsdp_axis=ctx.fsdp_axis,
                 routing=ctx.moe_routing,
@@ -175,20 +192,23 @@ def _block_apply(bt: str, p, x, cfg: ModelConfig, ctx: ModelContext, positions):
         x = G.rglru_apply(p, x, cfg)
     else:
         raise ValueError(bt)
-    return x, aux, cache
+    return x, moe, cache
 
 
 def _block_decode(bt: str, p, x_t, cache, cur_len, cfg: ModelConfig, ctx: ModelContext):
     """One-token decode for one block.  Returns (x_t, new_cache)."""
-    if bt in ("attn_mlp", "attn_moe", "lattn_mlp"):
+    if bt in _ATTN_BLOCKS:
         window = cfg.window if bt == "lattn_mlp" else None
         xn = L.rmsnorm(x_t, p["attn_norm"], eps=cfg.rms_eps)
-        a, ck, cv = A.attn_decode_step(
-            p["attn"], xn, cache["k"], cache["v"], cur_len, cfg, window=window
-        )
+        if bt.startswith("mla_"):
+            a, ck, cv = A.mla_decode_step(p["attn"], xn, cache["k"], cache["v"], cur_len, cfg)
+        else:
+            a, ck, cv = A.attn_decode_step(
+                p["attn"], xn, cache["k"], cache["v"], cur_len, cfg, window=window
+            )
         x_t = x_t + a
         xn2 = L.rmsnorm(x_t, p["mlp_norm"], eps=cfg.rms_eps)
-        if bt == "attn_moe":
+        if bt in _MOE_BLOCKS:
             mo, _ = M.moe_apply(
                 p["moe"], xn2, cfg, mesh=ctx.mesh, batch_axes=ctx.batch_axes,
                 model_axis=ctx.model_axis, fsdp_axis=ctx.fsdp_axis,
@@ -240,16 +260,29 @@ def _embed(params, batch, cfg: ModelConfig, ctx: ModelContext):
 
 
 def _stack_forward(params, x, cfg: ModelConfig, ctx: ModelContext, positions):
-    """Scan over the repeating unit + tail.  Returns (x, total_aux)."""
+    """The lead blocks, the scan over the repeating unit, the tail.  Returns
+    (x, moe): moe maps each expert block's path ("unit/slot0", "lead/lead0")
+    to its routing statistics with a leading layer axis."""
     unit = cfg.scan_unit
+    moe = {}
 
-    def unit_body(carry, unit_p):
-        x, aux = carry
+    def single(group, name, bt, x):
+        x, st, _ = _block_apply(bt, params[group][name], x, cfg, ctx, positions)
+        if st is not None:
+            moe[f"{group}/{name}"] = jax.tree_util.tree_map(lambda a: a[None], st)
+        return x
+
+    for li, bt in enumerate(cfg.lead):
+        x = single("lead", f"lead{li}", bt, x)
+
+    def unit_body(x, unit_p):
+        stats = {}
         for si, bt in enumerate(unit):
-            x, a, _ = _block_apply(bt, unit_p[f"slot{si}"], x, cfg, ctx, positions)
-            aux = aux + a
+            x, st, _ = _block_apply(bt, unit_p[f"slot{si}"], x, cfg, ctx, positions)
+            if st is not None:
+                stats[f"slot{si}"] = st
         x = ctx.constrain(x, ctx.batch_spec, None, None)
-        return (x, aux), ()
+        return x, stats
 
     body = unit_body
     if ctx.remat == "full":
@@ -259,13 +292,11 @@ def _stack_forward(params, x, cfg: ModelConfig, ctx: ModelContext, positions):
             unit_body, prevent_cse=False,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         )
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), params["unit"])
+    x, unit_stats = jax.lax.scan(body, x, params["unit"])
+    moe.update({f"unit/{k}": v for k, v in unit_stats.items()})
     for ti, bt in enumerate(cfg.tail):
-        x, a, _ = _block_apply(
-            bt, params["tail"][f"tail{ti}"], x, cfg, ctx, positions
-        )
-        aux = aux + a
-    return x, aux
+        x = single("tail", f"tail{ti}", bt, x)
+    return x, moe
 
 
 def _logits(params, x, cfg: ModelConfig, ctx: ModelContext):
@@ -284,12 +315,13 @@ def _logits(params, x, cfg: ModelConfig, ctx: ModelContext):
 
 
 def forward_train(params, batch, cfg: ModelConfig, ctx: ModelContext):
-    """Full training forward.  Returns (logits, aux_loss, label_mask)."""
+    """Full training forward.  Returns (logits, moe, label_mask), moe the
+    expert blocks' routing statistics by path (empty without experts)."""
     x, mask = _embed(params, batch, cfg, ctx)
     T = x.shape[1]
     positions = jnp.arange(T, dtype=jnp.int32)
-    x, aux = _stack_forward(params, x, cfg, ctx, positions)
-    return _logits(params, x, cfg, ctx), aux, mask
+    x, moe = _stack_forward(params, x, cfg, ctx, positions)
+    return _logits(params, x, cfg, ctx), moe, mask
 
 
 def loss_fn(params, batch, cfg: ModelConfig, ctx: ModelContext):
@@ -298,8 +330,13 @@ def loss_fn(params, batch, cfg: ModelConfig, ctx: ModelContext):
     ``batch["group_weights"]`` (G,) carries the paper's recovery weights b_g
     (zero at straggling groups); the batch's leading dim must be divisible by
     G.  Without the key, plain uniform weighting (b ≡ 1) is used.
+
+    With experts, each group's loss adds ``balance_weight`` × the mean over
+    its rows of the sequence-wise balance terms summed over the expert
+    layers, and ``metrics["moe"]`` holds per expert block the expert load
+    over all experts weighted as the rows are (``load``).
     """
-    logits, aux, mask = forward_train(params, batch, cfg, ctx)
+    logits, moe, mask = forward_train(params, batch, cfg, ctx)
     tokens = batch["tokens"]
     if cfg.num_codebooks > 0:
         targets = tokens[:, :, 1:]  # (B, K, T−1)
@@ -319,19 +356,44 @@ def loss_fn(params, batch, cfg: ModelConfig, ctx: ModelContext):
         m = mask[:, prefix:][:, 1:]
     B = ce.shape[0]
     gw = batch.get("group_weights")
+    G = 1 if gw is None else gw.shape[0]
+    row_w = jnp.ones((B,), jnp.float32) if gw is None else jnp.repeat(gw, B // G)
+
+    def weighted(per_group):
+        if gw is None:
+            return jnp.mean(per_group)
+        return jnp.sum(gw * per_group) / jnp.maximum(jnp.sum(gw), 1e-6)
+
     if gw is None:
         loss = jnp.sum(ce * m) / jnp.maximum(jnp.sum(m), 1.0)
     else:
-        G = gw.shape[0]
         ce_g = ce.reshape(G, -1)
         m_g = m.reshape(G, -1)
-        per_group = jnp.sum(ce_g * m_g, axis=1) / jnp.maximum(jnp.sum(m_g, axis=1), 1.0)
-        wsum = jnp.maximum(jnp.sum(gw), 1e-6)
-        loss = jnp.sum(gw * per_group) / wsum
-    aux_w = cfg.moe.router_aux_weight if cfg.moe else 0.0
-    total = loss + aux_w * aux / max(1, cfg.n_layers)
-    metrics = {"ce": loss, "aux": aux, "tokens": jnp.sum(m)}
+        loss = weighted(jnp.sum(ce_g * m_g, axis=1) / jnp.maximum(jnp.sum(m_g, axis=1), 1.0))
+    metrics = {"ce": loss, "aux": jnp.zeros((), jnp.float32), "tokens": jnp.sum(m)}
+    total = loss
+    if moe:
+        balance = sum(jnp.sum(st["balance"], axis=0) for st in moe.values())  # (B,)
+        metrics["aux"] = weighted(jnp.mean(balance.reshape(G, -1), axis=1))
+        total = loss + cfg.moe.balance_weight * metrics["aux"]
+        metrics["moe"] = {
+            path: {"load": jnp.einsum("lbe,b->le", st["load"], row_w)} for path, st in moe.items()
+        }
     return total, metrics
+
+
+def moe_bias_step(params, prev, load: dict, rate: float):
+    """``params`` with each expert block's selection bias set to its value in
+    ``prev`` moved by the block's load (see :func:`repro.models.moe.bias_step`):
+    the bias takes no gradient and no weight decay."""
+    params = dict(params)
+    for path, ld in load.items():
+        group, name = path.split("/")
+        blk = params[group][name]
+        b = prev[group][name]["moe"]["router_bias"]
+        moe = {**blk["moe"], "router_bias": M.bias_step(b, ld.reshape(b.shape), rate)}
+        params[group] = {**params[group], name: {**blk, "moe": moe}}
+    return params
 
 
 # ------------------------------------------------------------------ serve
@@ -339,6 +401,12 @@ def loss_fn(params, batch, cfg: ModelConfig, ctx: ModelContext):
 
 def _block_cache_init(bt: str, cfg: ModelConfig, B: int, max_len: int):
     dt = jnp.dtype(cfg.compute_dtype)
+    if bt.startswith("mla_"):
+        dk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        return {
+            "k": jnp.zeros((B, max_len, cfg.n_heads, dk), dt),
+            "v": jnp.zeros((B, max_len, cfg.n_heads, cfg.v_head_dim), dt),
+        }
     if bt in ("attn_mlp", "attn_moe"):
         s = max_len
         z = jnp.zeros((B, s, cfg.n_kv_heads, cfg.head_dim), dt)
@@ -365,6 +433,11 @@ def init_cache(cfg: ModelConfig, B: int, max_len: int):
             lambda l: jnp.broadcast_to(l[None], (reps,) + l.shape), one
         )
     cache = {"unit": unit_cache}
+    if cfg.lead:
+        cache["lead"] = {
+            f"lead{li}": _block_cache_init(bt, cfg, B, max_len)
+            for li, bt in enumerate(cfg.lead)
+        }
     if cfg.tail:
         cache["tail"] = {
             f"tail{ti}": _block_cache_init(bt, cfg, B, max_len)
@@ -387,6 +460,11 @@ def decode_step(params, cache, tokens_t, cur_len, cfg: ModelConfig, ctx: ModelCo
         x = jnp.take(params["embed"], tokens_t, axis=0).astype(compute_dtype)
     x = ctx.constrain(x, ctx.batch_spec, None, None)
     unit = cfg.scan_unit
+    lead_c = {}
+    for li, bt in enumerate(cfg.lead):
+        x, lead_c[f"lead{li}"] = _block_decode(
+            bt, params["lead"][f"lead{li}"], x, cache["lead"][f"lead{li}"], cur_len, cfg, ctx
+        )
 
     def unit_body(x, slices):
         unit_p, unit_c = slices
@@ -400,6 +478,8 @@ def decode_step(params, cache, tokens_t, cur_len, cfg: ModelConfig, ctx: ModelCo
 
     x, new_unit_cache = jax.lax.scan(unit_body, x, (params["unit"], cache["unit"]))
     new_cache = {"unit": new_unit_cache}
+    if cfg.lead:
+        new_cache["lead"] = lead_c
     if cfg.tail:
         tail_c = {}
         for ti, bt in enumerate(cfg.tail):
@@ -425,6 +505,11 @@ def prefill(params, batch, cfg: ModelConfig, ctx: ModelContext):
     T = x.shape[1]
     positions = jnp.arange(T, dtype=jnp.int32)
     unit = cfg.scan_unit
+    lead_c = {}
+    for li, bt in enumerate(cfg.lead):
+        x, _, lead_c[f"lead{li}"] = _block_apply(
+            bt, params["lead"][f"lead{li}"], x, cfg, ctx, positions
+        )
 
     def unit_body(carry, unit_p):
         x = carry
@@ -437,6 +522,8 @@ def prefill(params, batch, cfg: ModelConfig, ctx: ModelContext):
 
     x, unit_caches = jax.lax.scan(unit_body, x, params["unit"])
     cache = {"unit": unit_caches}
+    if cfg.lead:
+        cache["lead"] = lead_c
     if cfg.tail:
         tail_c = {}
         for ti, bt in enumerate(cfg.tail):

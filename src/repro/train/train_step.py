@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from ..analysis import compiled_path
 from ..models import transformer as T
-from ..models.registry import ModelConfig
+from ..models.registry import ModelConfig, MoEConfig
 from .compression import CompressionConfig, compress_with_error_feedback, init_ef_state
 from .optimizer import AdamWConfig, OptState, adamw_update, init_opt_state
 
@@ -120,11 +120,28 @@ def make_train_step(
             grads, ef = compress_with_error_feedback(compression, grads, ef)
         params, opt, opt_metrics = adamw_update(opt_cfg, state.params, grads, state.opt)
         metrics = dict(metrics)
+        moe = metrics.pop("moe", None)
+        if moe:
+            load = {k: v["load"] for k, v in moe.items()}
+            params = T.moe_bias_step(params, state.params, load, cfg.moe.bias_update_rate)
+            metrics.update(moe_metrics(load, cfg.moe.held))
         metrics.update(opt_metrics)
         metrics["loss"] = loss
         return TrainState(params=params, opt=opt, ef=ef), metrics
 
     return train_step
+
+
+def moe_metrics(load: dict, held: int) -> dict:
+    """A step's routing counts from the per-block expert loads: the pairs on
+    the held experts (the first ``held``), summed over the layers, and the
+    busiest held expert of any layer over the mean held expert."""
+    per = jnp.concatenate([v[..., :held].reshape(-1) for v in load.values()])
+    pairs = jnp.sum(per)
+    return {
+        "moe_local_pairs": pairs,
+        "moe_load_max_ratio": jnp.max(per) * per.size / jnp.maximum(pairs, 1.0),
+    }
 
 
 @compiled_path("train.group_grad", kind="factory")
@@ -147,6 +164,8 @@ def make_group_grad_fn(cfg: ModelConfig, ctx: T.ModelContext):
     The function returns the group's **shard-sum** statistics
     ``{"grads", "loss", "ce", "tok"}`` — per-shard token-normalized losses
     summed over the group's valid shard slots, and the gradient of that sum.
+    With experts it adds ``moe_load`` (the expert load of the valid slots,
+    combined like the gradients, so it counts the tokens the update covers).
     The executor's Lemma-3 combine then yields  Σ_g b_g Σ_{s∈P_g} ∇L̄_s
     = Σ_s a_s ∇L̄_s  with ``a = bᵀA ∈ [1, 1+δ]ⁿ``: for δ = 0 (fractional
     repetition under any coverage-preserving pattern) this is EXACTLY
@@ -168,12 +187,16 @@ def make_group_grad_fn(cfg: ModelConfig, ctx: T.ModelContext):
                 p, {"tokens": tokens, "group_weights": valid}, cfg, ctx
             )
             n_valid = jnp.sum(valid)
-            return total * n_valid, (metrics["ce"] * n_valid, metrics["tokens"])
+            return total * n_valid, (metrics["ce"] * n_valid, metrics["tokens"],
+                                     metrics.get("moe"))
 
-        (loss_sum, (ce_sum, tok)), grads = jax.value_and_grad(
+        (loss_sum, (ce_sum, tok, moe)), grads = jax.value_and_grad(
             shard_sum_loss, has_aux=True
         )(params)
-        return {"grads": grads, "loss": loss_sum, "ce": ce_sum, "tok": tok}
+        out = {"grads": grads, "loss": loss_sum, "ce": ce_sum, "tok": tok}
+        if moe:
+            out["moe_load"] = {k: v["load"] for k, v in moe.items()}
+        return out
 
     return group_stats
 
@@ -184,6 +207,7 @@ def make_recovered_apply_fn(
     num_shards: int,
     *,
     compression: Optional[CompressionConfig] = None,
+    moe: Optional[MoEConfig] = None,
 ):
     """Returns ``apply(state, stats) -> (state, metrics)``, ready to jit.
 
@@ -192,6 +216,9 @@ def make_recovered_apply_fn(
     the TOTAL shard count ``n`` — a pattern-independent constant — recovers
     the mean-loss gradient, so straggler and no-straggler steps apply
     numerically identical updates whenever the recovery band is exact.
+    With experts (``moe``), the selection biases move against the combined
+    load (see :func:`repro.models.moe.bias_step`), and the metrics carry the
+    routing counts of the tokens the update covers (:func:`moe_metrics`).
     """
     scale = 1.0 / float(num_shards)
 
@@ -208,6 +235,13 @@ def make_recovered_apply_fn(
             "ce": stats["ce"] * scale,
             "tokens": stats["tok"],
         }
+        if "moe_load" in stats:
+            # A load counts whole pairs: the combine's weights carry the
+            # on-device solve's rounding, which would tip sign(mean − load)
+            # for an expert exactly at the mean.
+            load = jax.tree_util.tree_map(jnp.round, stats["moe_load"])
+            params = T.moe_bias_step(params, state.params, load, moe.bias_update_rate)
+            metrics.update(moe_metrics(load, moe.held))
         metrics.update(opt_metrics)
         return TrainState(params=params, opt=opt, ef=ef), metrics
 

@@ -36,7 +36,7 @@ import numpy as np
 
 from ..analysis import compiled_path
 from ..core.resilience import ElasticPolicy, ResilienceSession
-from ..obs import trace_span
+from ..obs import default_registry, trace_span
 from ..kernels import autotune
 from ..core.stragglers import StragglerScenario, make_scenario
 from ..data.pipeline import RedundantDataPipeline
@@ -67,14 +67,32 @@ def _jitted_train_step(cfg, ctx, opt_cfg, compression):
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_apply_fn(opt_cfg, num_shards, compression):
+def _jitted_apply_fn(opt_cfg, num_shards, compression, moe=None):
     # The state is donated: the update is written in place, so a step never
     # holds two copies of the parameters and optimizer moments (at published
     # model widths a second copy does not fit a chip).
     return jax.jit(
-        make_recovered_apply_fn(opt_cfg, num_shards, compression=compression),
+        make_recovered_apply_fn(
+            opt_cfg, num_shards, compression=compression, moe=moe),
         donate_argnums=0,
     )
+
+
+_MOE_KEYS = ("moe_local_pairs", "moe_load_max_ratio")
+
+
+def _count_moe(host: dict) -> None:
+    """A step's routing counts into the registry: ``moe_local_pairs`` (pairs
+    on the held experts of the tokens the update covers, over every layer),
+    ``moe_steps`` and the gauge ``moe_load_max_ratio``.  ``moe_dropped_pairs``
+    stays 0: the expert layer's rows hold every routing (``models/moe.py``)."""
+    reg = default_registry()
+    reg.counter("moe_local_pairs", help="pairs on the held experts, covered tokens").inc(
+        float(host["moe_local_pairs"]))
+    reg.counter("moe_steps", help="steps that ran the expert layers").inc(1)
+    reg.counter("moe_dropped_pairs", help="routed pairs left uncomputed: none, dropless")
+    reg.gauge("moe_load_max_ratio", help="busiest held expert over the mean").set(
+        float(host["moe_load_max_ratio"]))
 
 
 @dataclasses.dataclass
@@ -188,7 +206,8 @@ class Trainer:
         # cache on fn identity, so these must be created exactly once.
         self._group_fn = make_group_grad_fn(self.cfg, self.ctx)
         self._apply_fn = _jitted_apply_fn(
-            self.opt_cfg, self.plan.num_shards, tcfg.compression
+            self.opt_cfg, self.plan.num_shards, tcfg.compression,
+            self.cfg.moe,
         )
         self._place_resident(full=False)
         self.plan.session.add_patch_listener(self._on_patch)
@@ -299,6 +318,7 @@ class Trainer:
                 "ce": metrics["ce"],
                 "grad_norm": metrics["grad_norm"],
                 "b_sum": jnp.sum(b_dev),
+                **{k: metrics[k] for k in _MOE_KEYS if k in metrics},
             }
         )
         record = {
@@ -313,6 +333,9 @@ class Trainer:
             "device_solves": sess.stats.device_solves,
             "patches": sess.stats.elastic_patches,
         }
+        if "moe_local_pairs" in host:
+            _count_moe(host)
+            record["local_pairs"] = int(host["moe_local_pairs"])
         return state, record
 
     # ------------------------------------------------------------- warm-up
@@ -409,8 +432,10 @@ class Trainer:
                 with trace_span(
                     "trainer.step", step=step, path="device_recovery",
                     stragglers=int((~alive_t).sum()),
-                ):
+                ) as span:
                     state, record = self._device_recovery_step(state, step, alive_t)
+                    if record is not None and "local_pairs" in record:
+                        span.set_attr(local_pairs=record["local_pairs"])
                 if record is None:
                     self.history.append({"step": step, "skipped": True})
                     continue

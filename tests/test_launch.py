@@ -152,10 +152,12 @@ def test_moe_local_routing_matches_pjit_routing():
         kw = dict(mesh=mesh, batch_axes=("data",), model_axis="model", fsdp_axis="data")
         o1, a1 = M.moe_apply(params, x, cfg, routing="pjit", **kw)
         o2, a2 = M.moe_apply(params, x, cfg, routing="local", **kw)
-        # Same capacity per shard in both paths → identical routing decisions.
+        # Dropless in both paths → identical routing decisions and counts.
         np.testing.assert_allclose(np.asarray(o1, np.float32),
                                    np.asarray(o2, np.float32), rtol=2e-4, atol=2e-4)
-        print("EQUAL aux", float(a1), float(a2))
+        np.testing.assert_array_equal(np.asarray(a1["held"]), np.asarray(a2["held"]))
+        assert int(a1["held"].sum()) == 8 * 16 * cfg.moe.top_k  # every pair, none dropped
+        print("EQUAL aux", float(a1["balance"].mean()), float(a2["balance"].mean()))
         """
     )
     out = _run_sub(code)
@@ -185,7 +187,7 @@ def test_moe_shard_map_lowering_mini():
             out, aux = M.moe_apply(p, x, cfg, mesh=mesh,
                                    batch_axes=("data",), model_axis="model",
                                    fsdp_axis="data")
-            return out.sum() + aux
+            return out.sum() + aux["balance"].sum()
         comp = jax.jit(f, in_shardings=(p_sh, None)).lower(params, x).compile()
         txt = comp.as_text()
         print("psum:", "all-reduce" in txt)

@@ -18,7 +18,7 @@ ARCH_MODULES = {
     "qwen3-8b": "qwen3_8b",
     "qwen2.5-3b": "qwen2_5_3b",
     "qwen3-1.7b": "qwen3_1_7b",
-    "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "deepseek-moe-16b": "deepseek_moe_16b",
     "xlstm-1.3b": "xlstm_1_3b",
     "internvl2-1b": "internvl2_1b",
@@ -59,7 +59,7 @@ def test_full_config_matches_assignment(arch):
         "qwen3-8b": (36, 4096, 32, 8, 12288, 151936),
         "qwen2.5-3b": (36, 2048, 16, 2, 11008, 151936),
         "qwen3-1.7b": (28, 2048, 16, 8, 6144, 151936),
-        "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
         "deepseek-moe-16b": (28, 2048, 16, 16, 1408, 102400),
         "xlstm-1.3b": (48, 2048, 4, 4, 0, 50304),
         "internvl2-1b": (24, 896, 14, 2, 4864, 151655),
@@ -210,17 +210,30 @@ def test_rglru_decode_consistency():
 
 
 def test_moe_capacity_routing_mass():
-    """Router mass reaching experts ≈ top-k probability mass (capacity 1.25
-    drops little at uniform load); output is finite and shaped."""
+    """Dropless routing: the full top-k mass reaches the experts.  With every
+    expert computing the same FFN, the routed output is each token's top-k
+    router mass times that FFN, whatever the load; every pair is computed."""
+    import dataclasses
+
     cfg = smoke_cfg("deepseek-moe-16b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, num_shared=0))
+    from repro.models import layers as L
     from repro.models import moe as M
 
     params = M.moe_init(jax.random.PRNGKey(0), cfg)
+    for k in ("w_gate", "w_up", "w_down"):
+        params[k] = jnp.broadcast_to(params[k][:1], params[k].shape)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model), jnp.float32)
-    out, aux = M.moe_apply(params, x, cfg)
+    out, stats = M.moe_apply(params, x, cfg)
     assert out.shape == x.shape
-    assert np.isfinite(np.asarray(out, np.float32)).all()
-    assert float(aux) > 0.5  # aux ≈ 1 at uniform routing
+    flat = x.reshape(-1, cfg.d_model)
+    _, w, _ = M.route(params["router"], None, flat, cfg.moe)
+    one = {"gate": params["w_gate"][0], "up": params["w_up"][0], "down": params["w_down"][0]}
+    ffn = L.mlp_apply(one, flat, act=cfg.mlp_act, compute_dtype=jnp.float32)
+    want = (jnp.sum(w, axis=1, keepdims=True) * ffn).reshape(x.shape)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want), rtol=3e-2, atol=3e-2)
+    assert int(jnp.sum(stats["held"])) == flat.shape[0] * cfg.moe.top_k  # none dropped
+    assert float(jnp.mean(stats["balance"])) > 0.5  # ≈ 1 at uniform routing
 
 
 def test_prefill_returns_last_position_logits_and_cache():
