@@ -21,12 +21,16 @@ Constructions implemented here:
   style); tolerates ``ℓ − 1`` adversarial stragglers.
 
 All constructions are plain numpy — the assignment is coordinator-side
-metadata, never device-resident tensor compute.
+metadata, never device-resident tensor compute.  The one piece of it kept on
+the device is its slot table (:attr:`Assignment.device_slot_rows`), by which
+:func:`repro.core.kmedian.pack_local_shards` gathers the workers' shards
+there.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import math
 from typing import Iterable, Optional
@@ -75,6 +79,30 @@ class Assignment:
     def shards_of(self, node: int) -> np.ndarray:
         """Shard indices assigned to ``node`` (the set ``P_i``)."""
         return np.flatnonzero(self.matrix[node])
+
+    @functools.cached_property
+    def slot_table(self) -> tuple[np.ndarray, np.ndarray]:
+        """Each node's shards in ``m`` padded slots, ``m`` the largest load
+        (at least 1): an (s, m) int32 shard index, −1 past the node's load,
+        and the (s, m) float32 weights, 1 on a shard and 0 on padding.
+        Built once (the assignment is immutable) and read-only."""
+        loads = [self.shards_of(i) for i in range(self.num_nodes)]
+        m = max((len(l) for l in loads), default=1) or 1
+        rows = np.full((self.num_nodes, m), -1, dtype=np.int32)
+        for i, l in enumerate(loads):
+            rows[i, : len(l)] = l
+        weights = (rows >= 0).astype(np.float32)
+        rows.setflags(write=False)
+        weights.setflags(write=False)
+        return rows, weights
+
+    @functools.cached_property
+    def device_slot_rows(self):
+        """:attr:`slot_table`'s shard indices on the default device,
+        uploaded once per assignment (an elastic patch makes a new one)."""
+        import jax.numpy as jnp
+
+        return jnp.asarray(self.slot_table[0])
 
     def nodes_of(self, shard: int) -> np.ndarray:
         """Node indices holding ``shard`` (the set ``A_p``)."""

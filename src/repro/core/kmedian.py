@@ -56,26 +56,34 @@ class ResilientClusteringOutput:
     summary_weights: np.ndarray  # b-weighted center weights (s·k,); 0 at stragglers
 
 
-def pack_local_shards(
-    points: np.ndarray, assignment: Assignment
-) -> tuple[np.ndarray, np.ndarray]:
-    """Pad per-node shards to the max load: (s, m, d) data + (s, m) weights.
+@jax.jit
+def _gather_slots(points, rows):
+    """(n, d) points into (s, m, d) slots by an (s, m) row table; a −1 slot
+    is an exact zero row."""
+    return jnp.where((rows >= 0)[..., None], points[jnp.maximum(rows, 0)], 0)
+
+
+def pack_local_shards(points, assignment: Assignment) -> tuple[jax.Array, np.ndarray]:
+    """Pad per-node shards to the max load: (s, m, d) data on the device +
+    (s, m) weights on the host.
 
     Padding rows are zeros with weight 0 — inert in every weighted statistic.
     Row ``i`` is exactly the data the assignment matrix maps to node ``i``,
     so sharding the stacked array over a device mesh's node axis IS the
-    paper's data placement.
+    paper's data placement.  ``points`` (host or device) go to the device
+    once — a no-op for a device array — and the stack is one compiled gather
+    there by the assignment's slot table, uploaded once per assignment
+    (:attr:`Assignment.device_slot_rows`).  The weights are a fresh writable
+    copy.
     """
-    s = assignment.num_nodes
-    loads = [assignment.shards_of(i) for i in range(s)]
-    m = max((len(l) for l in loads), default=1) or 1
-    d = points.shape[1]
-    xs = np.zeros((s, m, d), dtype=np.float32)
-    ws = np.zeros((s, m), dtype=np.float32)
-    for i, l in enumerate(loads):
-        xs[i, : len(l)] = points[l]
-        ws[i, : len(l)] = 1.0
-    return xs, ws
+    pts = jnp.asarray(points, jnp.float32)
+    if pts.ndim != 2 or pts.shape[0] < assignment.num_shards:
+        raise ValueError(
+            f"points of shape {pts.shape} do not hold the assignment's "
+            f"{assignment.num_shards} shards as rows"
+        )
+    _, ws = assignment.slot_table
+    return _gather_slots(pts, assignment.device_slot_rows), ws.copy()
 
 
 def prepare_resilient_run(
@@ -172,9 +180,9 @@ def local_cluster_batch(
 
 
 def _coordinator_pipeline(
-    points: np.ndarray,
+    points,
     k: int,
-    xs: np.ndarray,
+    xs,
     ws: np.ndarray,
     b_full: np.ndarray,
     ex: Executor,
@@ -187,11 +195,17 @@ def _coordinator_pipeline(
     """Shared steps 2–3: local solves (via executor), b-weighted fixed-shape
     union, coordinator weighted k-median, full-dataset cost.
 
-    Four sibling spans: ``kmedian.upload`` (the packed shards handed to the
-    device), then ``kmedian.local``, ``kmedian.coordinator`` and
-    ``kmedian.cost``, each ending where its result is on the host."""
+    ``points`` and ``xs`` may already be on the device (as
+    :meth:`~repro.core.resilience.ResilienceSession.prepare` hands them
+    over), and are then not copied again.  Four sibling spans:
+    ``kmedian.upload`` (``b_full`` and whichever of the shards and weights
+    are still on the host, handed to the device; ``bytes`` counts the
+    shards and weights that cross), then
+    ``kmedian.local``, ``kmedian.coordinator`` and ``kmedian.cost``, each
+    ending where its result is on the host."""
     s, m, d = xs.shape
-    with trace_span("kmedian.upload", rows=s * m, bytes=xs.nbytes + ws.nbytes):
+    crossing = sum(a.nbytes for a in (xs, ws) if not isinstance(a, jax.Array))
+    with trace_span("kmedian.upload", rows=s * m, bytes=crossing):
         # Not waited for: the span ends once the copy is handed to the
         # runtime, and the rest of the transfer shows under kmedian.local,
         # where the local solves wait for it.  A wait here would only keep
@@ -269,7 +283,7 @@ def ignore_stragglers_kmedian(
     """The paper's Fig 1(b) baseline: no recovery weighting — alive workers'
     centers are combined as-is (b ≡ 1 on the alive set).  With a
     non-redundant assignment this silently drops the stragglers' data."""
-    points = np.asarray(points, dtype=np.float32)
+    points = jnp.asarray(points, jnp.float32)  # one upload, for the pack and the cost
     alive = np.asarray(alive, dtype=bool)
     if not alive.any():
         raise ValueError("no surviving nodes with data — cannot form union")

@@ -4,8 +4,8 @@ The paper treats a straggler pattern as a one-shot event: draw a mask, solve
 the recovery LP, combine.  A *run* on a real cluster is a stream of patterns
 (correlated, persistent, adversarial — see :mod:`repro.core.stragglers`), and
 re-running the host prelude per call wastes exactly the state that stays
-fixed across rounds: the assignment, the packed shards, their device
-placement, and every previously-solved pattern.  :class:`ResilienceSession`
+fixed across rounds: the assignment, the fused step's device-resident
+shards, and every previously-solved pattern.  :class:`ResilienceSession`
 owns that state for a whole run:
 
 * **One pattern-keyed cache** (alive-mask bytes → ``RecoveryResult``) shared
@@ -108,6 +108,7 @@ class SessionStats(StatsView):
         "rounds": "observe() calls",
         "uncovered_rounds": "rounds where some shard had no alive replica",
         "placement_reoptimizes": "placement re-optimizations (permanent loss/join)",
+        "device_packs": "worker-shard packs gathered on the device",
     }
 
 
@@ -169,16 +170,8 @@ class ResilienceSession:
         # assignment — the same incremental discipline as _replace_moved_blocks
         # without the session having to know every consumer's data layout.
         self._patch_listeners: list = []
-        # Host-side packed shards, keyed by the caller's points object.
-        self._pack_src = None
-        self._pack_fp: Optional[bytes] = None
-        self._pack_version = -1
-        self._packed: Optional[tuple[np.ndarray, np.ndarray]] = None
-        self._packed_pts: Optional[np.ndarray] = None
-        # Device-resident (placed) arrays for the fused step_cost path.
-        # Keyed by its OWN source object: the host pack cache may move to a
-        # different points array (prepare() with a second dataset) without
-        # invalidating the resident placement.
+        # Device-resident (placed) arrays for the fused step_cost path, keyed
+        # by the caller's points object, its content and the version.
         self._resident = None  # (xs_placed, ws_placed, A_placed)
         self._resident_src = None
         self._resident_fp: Optional[bytes] = None
@@ -272,23 +265,47 @@ class ResilienceSession:
 
     def prepare(self, points, alive):
         """The shared prelude of every distributed algorithm: dtype coercion,
-        cached recovery solve, all-dead guard, packed shards (cached per
-        points object and assignment version).
+        cached recovery solve, all-dead guard, packed shards.
 
         Returns ``(points, alive, rec, executor, xs, ws)`` — the tuple
         :func:`repro.core.kmedian.prepare_resilient_run` used to rebuild from
-        scratch on every call.
+        scratch on every call — with ``points`` and ``xs`` on the device.
         """
         alive = np.asarray(alive, dtype=bool)
         rec = self.recovery(alive)
         self.validate_coverage(alive, rec)  # cached per pattern, raises all-dead
-        pts32, xs, ws = self._packed_shards(points)
-        return pts32, alive, rec, self.executor, xs, ws
+        pts, xs, ws = self._packed_shards(points)
+        return pts, alive, rec, self.executor, xs, ws
+
+    def _packed_shards(self, points):
+        with trace_span("session.pack", **self._obs_labels) as sp:
+            return self._pack(points, sp)
+
+    def _pack(self, points, sp):
+        """The caller's points on the device, uploaded once (a no-op for a
+        device array), and the workers' shards gathered from them there;
+        ``sp`` (the enclosing ``session.pack`` span) is told rows, bytes and
+        the bytes uploaded.  Packed afresh on every call: an in-place edit of
+        the caller's array can never serve a stale pack, and no content hash
+        is needed."""
+        import jax
+        import jax.numpy as jnp
+
+        from .kmedian import pack_local_shards  # looked up per call
+
+        pts = jnp.asarray(points, jnp.float32)
+        xs, ws = pack_local_shards(pts, self.assignment)
+        self.stats.device_packs += 1
+        sp.set_attr(
+            rows=xs.shape[0] * xs.shape[1], bytes=xs.nbytes + ws.nbytes,
+            uploaded=0 if isinstance(points, jax.Array) else pts.nbytes,
+        )
+        return pts, xs, ws
 
     @staticmethod
     def _fingerprint(points) -> bytes:
-        """Cheap content hash: identity alone would serve stale packs after
-        an in-place mutation of the caller's array (pts *= 0.5)."""
+        """Cheap content hash: identity alone would serve a stale resident
+        placement after an in-place mutation of the caller's array."""
         with trace_span("session.fingerprint") as sp:
             a = np.ascontiguousarray(np.asarray(points))
             h = hashlib.blake2b(digest_size=16)
@@ -296,31 +313,6 @@ class ResilienceSession:
             h.update(a.tobytes())
             sp.set_attr(bytes=a.nbytes)
             return h.digest()
-
-    def _packed_shards(self, points):
-        with trace_span("session.pack", **self._obs_labels) as sp:
-            return self._pack(points, self._fingerprint(points), sp)
-
-    def _pack(self, points, fp: bytes, sp):
-        """The host pack for ``points`` (fingerprint ``fp``), cached per
-        points object, content and assignment version; ``sp`` (the
-        enclosing ``session.pack`` span) is told rows, bytes and hit."""
-        hit = self._packed is not None and self._pack_src is points and (
-            self._pack_version == self.version and self._pack_fp == fp
-        )
-        if not hit:
-            from .kmedian import pack_local_shards
-
-            pts32 = np.asarray(points, dtype=np.float32)
-            xs, ws = pack_local_shards(pts32, self.assignment)
-            self._pack_src = points
-            self._pack_fp = fp
-            self._packed_pts = pts32
-            self._packed = (xs, ws)
-            self._pack_version = self.version
-        xs, ws = self._packed
-        sp.set_attr(hit=hit, rows=xs.shape[0] * xs.shape[1], bytes=xs.nbytes + ws.nbytes)
-        return self._packed_pts, xs, ws
 
     # ------------------------------------------------ fused on-device path
 
@@ -334,7 +326,7 @@ class ResilienceSession:
             ):
                 sp.set_attr(hit=True)
                 return self._resident
-            _, xs, ws = self._pack(points, fp, sp)
+            _, xs, ws = self._pack(points, sp)
             sp.set_attr(placed=True)
             ex = self.executor
             self._resident = (
@@ -665,8 +657,8 @@ class ResilienceSession:
         :class:`repro.core.placement.PlacementOptimizer`; returns the node
         rows that changed.  Cache invalidation is SELECTIVE — only entries
         where some changed node is alive can see the new matrix rows
-        (same validity rule as elastic patches) — but the packed/resident
-        arrays are rebuilt wholesale, since a re-optimization typically
+        (same validity rule as elastic patches) — but the resident arrays
+        are rebuilt wholesale, since a re-optimization typically
         moves many rows at once."""
         live = self.alive_mask()
         with trace_span(
@@ -690,8 +682,6 @@ class ResilienceSession:
             self._invalidate_patterns(changed.tolist())
             self.stats.placement_reoptimizes += 1
             self.version += 1
-            self._packed = None
-            self._pack_version = -1
             self._resident = None
             self._resident_version = -1
             self.stats.full_repacks += 1
@@ -744,14 +734,12 @@ class ResilienceSession:
                 fresh, matrix=mat, scheme="elastic_cyclic"
             )
         self._assignment_lineage.add(id(self.assignment))
-        # The whole matrix changed: every cached pattern, pack, and resident
+        # The whole matrix changed: every cached pattern and the resident
         # placement is stale (unlike _patch's selective invalidation).
         self.stats.cache_invalidations += len(self._cache)
         self._cache.clear()
         self._coverage.clear()
         self._covers.clear()
-        self._packed = None
-        self._pack_version = -1
         self._resident = None
         self._resident_version = -1
         self.stats.reshards += 1
@@ -790,17 +778,16 @@ class ResilienceSession:
         node rows the patch touched are re-packed and re-placed (the mesh
         executor moves just those devices' blocks).  A patch that grows the
         maximum load needs wider padding → full repack on next use."""
-        if self._resident is None or self._pack_src is None:
+        if self._resident is None:
             return
         new_m = int(self.assignment.matrix.sum(axis=1).max())
         if (
             new_m > old_m  # wider padding needed: repack lazily
             or self._resident_version != self.version - 1
-            or self._resident_src is not self._pack_src  # pack moved datasets
         ):
             self._resident = None
             return
-        pts32 = self._packed_pts
+        pts32 = np.asarray(self._resident_src, dtype=np.float32)
         d = pts32.shape[1]
         xs_rows = np.zeros((len(moved_nodes), old_m, d), dtype=np.float32)
         ws_rows = np.zeros((len(moved_nodes), old_m), dtype=np.float32)
@@ -816,13 +803,4 @@ class ResilienceSession:
             ex.place_broadcast(self.assignment.matrix.astype(np.float32)),
         )
         self._resident_version = self.version
-        # Host pack cache: patch the same rows so prepare() stays coherent.
-        # Copy-on-patch — arrays already handed out by prepare() must not
-        # change under a caller mid-algorithm.
-        if self._packed is not None and self._pack_version == self.version - 1:
-            xs, ws = self._packed[0].copy(), self._packed[1].copy()
-            xs[moved_nodes] = xs_rows
-            ws[moved_nodes] = ws_rows
-            self._packed = (xs, ws)
-            self._pack_version = self.version
         self.stats.moved_node_blocks += len(moved_nodes)

@@ -259,9 +259,9 @@ def test_session_one_cache_across_algorithms_and_plan():
 
 
 def test_kmedian_span_tree_names_each_host_step():
-    """One solve records its pack (with the fingerprint inside it) and the
-    four pipeline steps as siblings, under no span that encloses the whole
-    solve; the second solve of the same array is a pack hit."""
+    """One solve records its pack (the upload and the device gather, with no
+    content hash inside it) and the four pipeline steps as siblings, under
+    no span that encloses the whole solve; every solve packs afresh."""
     from repro.core import ResilienceSession, cyclic_assignment
     from repro.obs import configure_buffer
     from repro.obs import trace as trace_mod
@@ -281,21 +281,17 @@ def test_kmedian_span_tree_names_each_host_step():
         trace_mod._BUFFER = prev
     rows.sort(key=lambda r: r["span"])  # ids are handed out as spans start
     names = [r["name"] for r in rows]
-    solve = ["session.pack", "session.fingerprint", "kmedian.upload", "kmedian.local",
+    solve = ["session.pack", "kmedian.upload", "kmedian.local",
              "kmedian.coordinator", "kmedian.cost"]
     assert names == ["session.recovery_solve"] + solve + solve
-    by_id = {r["span"]: r for r in rows}
-    for r in rows:
-        if r["name"] == "session.fingerprint":
-            assert by_id[r["parent"]]["name"] == "session.pack"
-        else:
-            assert r["parent"] is None
+    assert all(r["parent"] is None for r in rows)
     packs = [r for r in rows if r["name"] == "session.pack"]
-    assert [p["attrs"]["hit"] for p in packs] == [False, True]
-    assert packs[0]["attrs"]["rows"] == 4 * 48
-    assert packs[0]["attrs"]["bytes"] == 4 * 48 * 3 * 4 + 4 * 48 * 4
-    fp = next(r for r in rows if r["name"] == "session.fingerprint")
-    assert fp["attrs"]["bytes"] == pts.nbytes
+    for p in packs:
+        assert "hit" not in p["attrs"]
+        assert p["attrs"]["rows"] == 4 * 48
+        assert p["attrs"]["bytes"] == 4 * 48 * 3 * 4 + 4 * 48 * 4
+        assert p["attrs"]["uploaded"] == pts.nbytes
+    assert sess.stats.device_packs == 2
 
 
 def test_coverage_validation_computed_once_per_pattern():
